@@ -143,10 +143,10 @@ def load_trainer(path: str, trainer):
         template["async"] = engine.pending_template(extra["async"])
     tree = _unflatten_into(flat, template)
     if "base" in template:
-        # the jitted grad fn captured the constructor's base_params as a
-        # compile-time constant — a checkpoint carrying a *different*
-        # base would silently train against stale weights, so the match
-        # must be bitwise
+        # the jitted programs take trainer.base_params as an argument and
+        # the restore does not replace it — a checkpoint carrying a
+        # *different* base would silently continue against other
+        # weights than the saved run's, so the match must be bitwise
         for (key, saved), cur in zip(
                 sorted(_flatten(tree["base"]).items()),
                 (v for _, v in sorted(_flatten(trainer.base_params).items()))):

@@ -275,11 +275,13 @@ class AsyncBufferedEngine:
         drift rows instead of their means (the means happen at
         aggregation over the *buffered* rows)."""
         spec, solver, up, priv = self.spec, self.solver, self.up, self.priv
-        fn = partial(client_update, self.trainer._grad_fn, spec,
-                     use_fused_update=self.trainer._use_fused_update)
+        grad_fn_for = self.trainer._grad_fn_for
+        fused = self.trainer._use_fused_update
 
         def client_fn(x_cl, c_cl, c_i, batches, slots_in, res_in, k_up,
-                      k_priv, positions):
+                      k_priv, positions, base):
+            fn = partial(client_update, grad_fn_for(base), spec,
+                         use_fused_update=fused)
             dy, dc, c_i_new, slots_new, losses = jax.vmap(
                 fn, in_axes=(None, None, 0, 0, 0 if solver.stateful else None)
             )(x_cl, c_cl, c_i, batches, slots_in)
@@ -412,7 +414,7 @@ class AsyncBufferedEngine:
         self._ver_positions += g
         dy, dc, c_i_new, res_new, slots_new, losses, clipped = (
             self._client_fn(x_cl, c_cl, c_i, batches, slots, res, k_up,
-                            k_priv, positions))
+                            k_priv, positions, tr.base_params))
         payload = {"dy": dy, "dc": dc, "c_i": c_i_new, "loss": losses}
         if self.up.stateful:
             payload["residual"] = res_new

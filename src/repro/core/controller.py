@@ -302,11 +302,20 @@ class FederatedTrainer:
             k: float(v) for k, v in round_comm_bytes(
                 spec, self.server.x,
                 stateful_clients=self.algorithm.stateful_clients).items()}
-        grad_fn = make_grad_fn(loss_fn, space=self.update_space, spec=spec,
-                               base_params=self.base_params)
+        space = self.update_space
+
+        def grad_fn_for(base):
+            # the frozen base is an argument of every jitted program below
+            # (None, i.e. no argument at all, in the full space) and the
+            # delta grad fn is built from it at trace time: a closed-over
+            # base would be serialised into each program as a constant
+            return make_grad_fn(loss_fn, space=space, spec=spec,
+                                base_params=base)
+
+        grad_fn = grad_fn_for(self.base_params)
         # the async engine re-derives the per-dispatch client phase from
         # these (core/async_engine.py — DESIGN.md §14)
-        self._grad_fn = grad_fn
+        self._grad_fn_for = grad_fn_for
         self._use_fused_update = use_fused_update
         # megakernel capability gate (DESIGN.md §15): decided once at
         # trainer init from static config — "" when every local loop will
@@ -329,9 +338,10 @@ class FederatedTrainer:
                     f"use_megakernel requested but running the per-step "
                     f"path: {self.megakernel_fallback_reason}", stacklevel=2)
 
-        def round_fn(server, clients, batches, comp_key, priv_key, dp_round):
-            return run_round(grad_fn, spec, server, clients, batches,
-                             use_fused_update=use_fused_update,
+        def round_fn(server, clients, batches, comp_key, priv_key, dp_round,
+                     base):
+            return run_round(grad_fn_for(base), spec, server, clients,
+                             batches, use_fused_update=use_fused_update,
                              comp_key=comp_key, priv_key=priv_key,
                              dp_round=dp_round)
 
@@ -392,9 +402,9 @@ class FederatedTrainer:
             self._plan_futures: OrderedDict = OrderedDict()
 
             def cohort_fn(server, cohort, data, round_ids, slot_ids,
-                          data_key, comp_key, priv_key, weights, t0, R):
+                          data_key, comp_key, priv_key, weights, t0, R, base):
                 return run_rounds_cohort(
-                    grad_fn, spec, server, cohort, R, data=data,
+                    grad_fn_for(base), spec, server, cohort, R, data=data,
                     batch_fn=batch_fn, round_ids=round_ids,
                     slot_ids=slot_ids, data_key=data_key, comp_key=comp_key,
                     priv_key=priv_key, start_round=t0, weights=weights,
@@ -434,9 +444,9 @@ class FederatedTrainer:
                 self.device_store = c_store
 
             def chunk_fn(server, store, data, sample_key, data_key,
-                         comp_key, priv_key, sizes, t0, R):
+                         comp_key, priv_key, sizes, t0, R, base):
                 return run_rounds(
-                    grad_fn, spec, server, store, R, data=data,
+                    grad_fn_for(base), spec, server, store, R, data=data,
                     batch_fn=batch_fn, sample_key=sample_key,
                     data_key=data_key, comp_key=comp_key, priv_key=priv_key,
                     start_round=t0, sizes=sizes,
@@ -611,7 +621,7 @@ class FederatedTrainer:
                                           self.round_idx)
             dp_round = jnp.asarray(self.round_idx, jnp.int32)
         out = self.round_fn(self.server, clients, inp.batches, comp_key,
-                            priv_key, dp_round)
+                            priv_key, dp_round, self.base_params)
         self.server = out.server
         return out.clients, out.metrics
 
@@ -794,7 +804,7 @@ class FederatedTrainer:
             plan.slot_ids, self._data_base_key,
             self._comp_base_key if self._comp_keyed else None,
             self._priv_base_key if self._priv_active else None,
-            weights, t0, R)
+            weights, t0, R, self.base_params)
         self.server = server
         # gather-ahead for the next chunks while the device crunches this
         # one (async dispatch: nothing above blocked on the chunk yet)
@@ -819,7 +829,7 @@ class FederatedTrainer:
                 self.device_sampler.key, self._data_base_key,
                 self._comp_base_key if self._comp_keyed else None,
                 self._priv_base_key if self._priv_active else None,
-                self._device_sizes, self.round_idx, R)
+                self._device_sizes, self.round_idx, R, self.base_params)
             self.server, self.device_store = server, store
             self._host_store_dirty = True
         stacked = {k: np.asarray(v) for k, v in metrics.items()}

@@ -47,44 +47,65 @@ from repro.kernels.scaffold_update import ops, ref
 from repro.kernels.scaffold_update.kernel import LANES
 
 
-def _grad_terms(y, A_ref, b_ref, rows: int, dp: int):
+def _grad_terms(y, A_ref, b_ref, rows: int):
     """In-kernel quadratics gradient pieces for grid step k.
 
     Returns ``(Av, bm)`` with ``Av = sym(mean_b A_k) @ y`` and
     ``bm = mean_b b_k``, both fp32 ``(rows, LANES)``.
+
+    The matvec is a sum of 2-D ``(rows, 128) x (128, 128)`` matmuls, the
+    only contraction shape the TPU compiler takes here: with ``B`` the
+    ``(r', r)`` lane block of ``sym(mean A)``, output row ``r`` is
+    ``sum_r' y[r'] @ B``. Masking ``y`` to row ``r'`` before the matmul
+    leaves that product in row ``r'``; a sublane sum then moves it to
+    row ``r``.
     """
     A = A_ref[0].astype(jnp.float32)  # (bsz, dp, dp)
     Am = jnp.mean(A, axis=0)
     Am = 0.5 * (Am + Am.T)  # autodiff of 0.5 y^T A y is the symmetric part
-    bm = jnp.mean(b_ref[0].astype(jnp.float32), axis=0).reshape(rows, LANES)
-    Av = jax.lax.dot_general(
-        Am.reshape(dp, rows, LANES), y,
-        dimension_numbers=(((1, 2), (0, 1)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(rows, LANES)
+    bm = jnp.mean(b_ref[0].astype(jnp.float32), axis=0)  # (rows, LANES)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 0)
+    y_rows = [jnp.where(row == r, y, 0.0) for r in range(rows)]
+    Av = jnp.zeros((rows, LANES), jnp.float32)
+    for r in range(rows):
+        part = jnp.zeros((rows, LANES), jnp.float32)
+        for rp in range(rows):
+            part += jnp.dot(
+                y_rows[rp],
+                Am[rp * LANES:(rp + 1) * LANES, r * LANES:(r + 1) * LANES],
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+        Av = jnp.where(row == r, jnp.sum(part, axis=0, keepdims=True), Av)
     return Av, bm
 
 
+def _store_loss(loss_ref, k, loss):
+    """Write step k's loss into row k of the resident ``(K, 128)`` loss
+    block (a select over the whole block: no dynamic sublane store)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, loss_ref.shape, 0)
+    loss_ref[...] = jnp.where(rows == k, loss, loss_ref[...])
+
+
 def _local_loop_kernel(eta_ref, y0_ref, corr_ref, A_ref, b_ref,
-                       y_ref, loss_ref, *, rows: int, dp: int):
+                       y_ref, loss_ref, *, rows: int):
     """One grid step k of the fused sgd/sgd_sched local loop."""
     k = pl.program_id(0)
 
     @pl.when(k == 0)
     def _():
         y_ref[...] = y0_ref[...]
+        loss_ref[...] = jnp.zeros_like(loss_ref)
 
     y = y_ref[...].astype(jnp.float32)
-    Av, bm = _grad_terms(y, A_ref, b_ref, rows, dp)
+    Av, bm = _grad_terms(y, A_ref, b_ref, rows)
     loss = 0.5 * jnp.sum(Av * y) + jnp.sum(bm * y)
-    loss_ref[0, :] = jnp.full((LANES,), loss, jnp.float32)
+    _store_loss(loss_ref, k, loss)
     g = Av + bm + corr_ref[...].astype(jnp.float32)
     y_ref[...] = (y - eta_ref[k] * g).astype(y_ref.dtype)
 
 
 def _momentum_loop_kernel(eta_ref, y0_ref, corr_ref, m0_ref, A_ref, b_ref,
-                          y_ref, m_ref, loss_ref, *, rows: int, dp: int,
-                          beta: float):
+                          y_ref, m_ref, loss_ref, *, rows: int, beta: float):
     """One grid step k of the fused heavy-ball local loop:
     m <- beta*m + (g + corr);  y <- y - eta_k*m, with the fp32 momentum
     slot pinned in VMEM alongside the parameter buffer."""
@@ -94,11 +115,12 @@ def _momentum_loop_kernel(eta_ref, y0_ref, corr_ref, m0_ref, A_ref, b_ref,
     def _():
         y_ref[...] = y0_ref[...]
         m_ref[...] = m0_ref[...]
+        loss_ref[...] = jnp.zeros_like(loss_ref)
 
     y = y_ref[...].astype(jnp.float32)
-    Av, bm = _grad_terms(y, A_ref, b_ref, rows, dp)
+    Av, bm = _grad_terms(y, A_ref, b_ref, rows)
     loss = 0.5 * jnp.sum(Av * y) + jnp.sum(bm * y)
-    loss_ref[0, :] = jnp.full((LANES,), loss, jnp.float32)
+    _store_loss(loss_ref, k, loss)
     g = Av + bm + corr_ref[...].astype(jnp.float32)
     m = beta * m_ref[...] + g
     m_ref[...] = m
@@ -117,6 +139,7 @@ def scaffold_local_loop_2d(eta_table, y0, corr, A, b, *,
     K, bsz, dp = A.shape[0], A.shape[1], A.shape[2]
     rows = y0.shape[0]
     whole = pl.BlockSpec((rows, LANES), lambda k, _: (0, 0))
+    loss_block = pl.BlockSpec((K, LANES), lambda k, _: (0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(K,),
@@ -124,17 +147,17 @@ def scaffold_local_loop_2d(eta_table, y0, corr, A, b, *,
             whole,
             whole,
             pl.BlockSpec((1, bsz, dp, dp), lambda k, _: (k, 0, 0, 0)),
-            pl.BlockSpec((1, bsz, dp), lambda k, _: (k, 0, 0)),
+            pl.BlockSpec((1, bsz, rows, LANES), lambda k, _: (k, 0, 0, 0)),
         ],
-        out_specs=(whole, pl.BlockSpec((1, LANES), lambda k, _: (k, 0))),
+        out_specs=(whole, loss_block),
     )
     y_out, losses = pl.pallas_call(
-        partial(_local_loop_kernel, rows=rows, dp=dp),
+        partial(_local_loop_kernel, rows=rows),
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((rows, LANES), y0.dtype),
                    jax.ShapeDtypeStruct((K, LANES), jnp.float32)),
         interpret=interpret,
-    )(eta_table, y0, corr, A, b)
+    )(eta_table, y0, corr, A, b.reshape(K, bsz, rows, LANES))
     return y_out, losses[:, 0]
 
 
@@ -146,6 +169,7 @@ def scaffold_momentum_local_loop_2d(eta_table, y0, corr, m0, A, b, *,
     K, bsz, dp = A.shape[0], A.shape[1], A.shape[2]
     rows = y0.shape[0]
     whole = pl.BlockSpec((rows, LANES), lambda k, _: (0, 0))
+    loss_block = pl.BlockSpec((K, LANES), lambda k, _: (0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(K,),
@@ -154,19 +178,18 @@ def scaffold_momentum_local_loop_2d(eta_table, y0, corr, m0, A, b, *,
             whole,
             whole,
             pl.BlockSpec((1, bsz, dp, dp), lambda k, _: (k, 0, 0, 0)),
-            pl.BlockSpec((1, bsz, dp), lambda k, _: (k, 0, 0)),
+            pl.BlockSpec((1, bsz, rows, LANES), lambda k, _: (k, 0, 0, 0)),
         ],
-        out_specs=(whole, whole,
-                   pl.BlockSpec((1, LANES), lambda k, _: (k, 0))),
+        out_specs=(whole, whole, loss_block),
     )
     y_out, m_out, losses = pl.pallas_call(
-        partial(_momentum_loop_kernel, rows=rows, dp=dp, beta=float(beta)),
+        partial(_momentum_loop_kernel, rows=rows, beta=float(beta)),
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((rows, LANES), y0.dtype),
                    jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
                    jax.ShapeDtypeStruct((K, LANES), jnp.float32)),
         interpret=interpret,
-    )(eta_table, y0, corr, m0, A, b)
+    )(eta_table, y0, corr, m0, A, b.reshape(K, bsz, rows, LANES))
     return y_out, m_out, losses[:, 0]
 
 

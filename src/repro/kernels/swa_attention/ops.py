@@ -2,7 +2,8 @@
 
 Accepts the model-layer layout (B, S, H, D) and handles block-size
 selection + the non-TPU fallback (oracle on CPU unless interpret=True is
-forced for validation).
+forced for validation). On TPU a shape no block size divides is an
+error, never a silent oracle run.
 """
 from __future__ import annotations
 
@@ -33,6 +34,11 @@ def swa_attention(q, k, v, window: int, *, interpret: bool = False):
         while s % block or window % block:
             block //= 2
             if block < 8:
+                if _is_tpu():
+                    raise ValueError(
+                        f"swa_attention: no block size >= 8 divides both "
+                        f"seq {s} and window {window}; the kernel cannot "
+                        f"run on this shape")
                 out = ref.swa_attention_ref(qt, kt, vt, window)
                 break
         else:

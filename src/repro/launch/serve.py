@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.checkpoint import load_serving_params
 from repro.models import model as M
+from repro.util import use_repo_compile_cache
 
 
 def checkpoint_params(cfg, path: str):
@@ -84,6 +85,7 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=32)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
+    use_repo_compile_cache()
 
     from repro.launch.train import preset_config
 

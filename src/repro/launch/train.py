@@ -33,6 +33,7 @@ from repro.core import (
 from repro.optim.schedules import schedule_names
 from repro.data import SyntheticLMFederated
 from repro.models import model as M
+from repro.util import use_repo_compile_cache
 
 
 def preset_config(arch: str, preset: str):
@@ -220,6 +221,10 @@ def main(argv=None):
             print(f"{title}: {' '.join(names)}")
         return None
 
+    dev = jax.devices()[0]
+    print(f"platform={dev.platform} device_kind={dev.device_kind} "
+          f"devices={len(jax.devices())} "
+          f"compile_cache={use_repo_compile_cache()}")
     cfg = preset_config(args.arch, args.preset)
     spec = FedRoundSpec(
         algorithm=args.algorithm,

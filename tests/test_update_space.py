@@ -622,6 +622,40 @@ def test_train_merge_decode_end_to_end(tmp_path):
     assert bool(jnp.all((out >= 0) & (out < cfg.vocab_size)))
 
 
+def test_frozen_base_is_a_program_argument():
+    """The scanned chunk program takes the frozen base as a parameter: its
+    argument bytes include every base byte, and its HLO holds no constant
+    as large as a base weight (a closed-over base is compiled in as one,
+    bloating every program and its compile-cache key by the model size)."""
+    import re
+
+    from repro.configs import get_reduced
+    from repro.models import model as M
+
+    cfg = get_reduced("llama3.2-3b")
+    spec = _spec(num_clients=4, num_sampled=2, local_batch=1,
+                 update_space="lora", lora_rank=8)
+    ds = SyntheticLMFederated(4, cfg.vocab_size, seq_len=16, seed=0)
+    tr = FederatedTrainer(partial(M.loss_fn, cfg),
+                          partial(M.init_params, cfg), spec, ds, seed=0,
+                          scan_rounds=2)
+    assert tr.scan_active
+    compiled = tr._scan_fn.lower(
+        tr.server, tr.device_store, tr._device_data, tr.device_sampler.key,
+        tr._data_base_key, None, None, tr._device_sizes, 0, 2,
+        tr.base_params).compile()
+    base_leaves = jax.tree.leaves(tr.base_params)
+    base_bytes = sum(leaf.nbytes for leaf in base_leaves)
+    assert compiled.memory_analysis().argument_size_in_bytes >= base_bytes
+    smallest_weight = min(leaf.size for leaf in base_leaves if leaf.ndim >= 2)
+    consts = re.findall(r"= \w+\[([\d,]*)\]\S* constant\(",
+                        compiled.as_text())
+    sizes = [int(np.prod([int(d) for d in dims.split(",") if d]))
+             for dims in consts]
+    assert sizes and max(sizes) < smallest_weight, (max(sizes),
+                                                    smallest_weight)
+
+
 def test_list_registries_prints_nine(capsys):
     from repro.launch.train import main as train_main
 
