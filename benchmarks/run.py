@@ -23,7 +23,7 @@ def main(argv=None) -> None:
     ap = bench_argparser(__doc__.splitlines()[0])
     ap.add_argument("--only", default="",
                     help="comma list: fig3,table3,table4,table5,ablation,"
-                         "round,roofline")
+                         "round")
     args, _ = ap.parse_known_args(argv)
     if args.out_json not in ("", "-"):
         ap.error("run.py writes one BENCH_<section>.json per section "
@@ -107,12 +107,6 @@ def main(argv=None) -> None:
                  f"{modes['scanned']['speedup_vs_sync']:.2f}x")
         if emit_json:
             print("wrote", write_bench_json("round", rows))
-
-    if only is None or "roofline" in only:
-        from benchmarks import roofline
-
-        rows, us = _timed(roofline.main, mesh="16x16")
-        emit("roofline_artifacts", us, f"n_combos={len(rows)}")
 
     print("\n=== CSV (name,us_per_call,derived) ===")
     for line in csv_rows:

@@ -53,6 +53,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import spans
 from repro.core.tree import tree_sub, tree_zeros_like
 
 # ---------------------------------------------------------------------------
@@ -507,10 +508,13 @@ def run_rounds(grad_fn, spec, server: ServerState, client_store, R: int, *,
 
     def body(carry, t):
         server, store = carry
-        ids = device_sample_ids(sample_key, t, spec.num_clients,
-                                spec.num_sampled)
-        batches = batch_fn(data, ids, jax.random.fold_in(data_key, t))
-        gathered = tree_gather(store, ids)
+        with jax.named_scope(spans.SAMPLE):
+            ids = device_sample_ids(sample_key, t, spec.num_clients,
+                                    spec.num_sampled)
+        with jax.named_scope(spans.BATCHES):
+            batches = batch_fn(data, ids, jax.random.fold_in(data_key, t))
+        with jax.named_scope(spans.GATHER):
+            gathered = tree_gather(store, ids)
         clients = ClientRoundState(
             c_i=gathered["c_i"] if wrapped else gathered,
             uplink_residual=(gathered["residual"] if carry_residuals
@@ -534,7 +538,8 @@ def run_rounds(grad_fn, spec, server: ServerState, client_store, R: int, *,
                 new_rows["solver"] = out.clients.solver_slots
         else:
             new_rows = out.clients.c_i
-        store = tree_scatter(store, ids, new_rows)
+        with jax.named_scope(spans.SCATTER):
+            store = tree_scatter(store, ids, new_rows)
         return (out.server, store), out.metrics
 
     ts = jnp.arange(R, dtype=jnp.int32) + jnp.asarray(start_round, jnp.int32)
@@ -599,8 +604,10 @@ def run_rounds_cohort(grad_fn, spec, server: ServerState, cohort_store,
     def body(store_and_server, xs):
         server, store = store_and_server
         t, ids, slots = xs["t"], xs["ids"], xs["slots"]
-        batches = batch_fn(data, ids, jax.random.fold_in(data_key, t))
-        gathered = tree_gather(store, slots)
+        with jax.named_scope(spans.BATCHES):
+            batches = batch_fn(data, ids, jax.random.fold_in(data_key, t))
+        with jax.named_scope(spans.GATHER):
+            gathered = tree_gather(store, slots)
         clients = ClientRoundState(
             c_i=gathered["c_i"] if wrapped else gathered,
             uplink_residual=(gathered["residual"] if carry_residuals
@@ -623,7 +630,8 @@ def run_rounds_cohort(grad_fn, spec, server: ServerState, cohort_store,
                 new_rows["solver"] = out.clients.solver_slots
         else:
             new_rows = out.clients.c_i
-        store = tree_scatter(store, slots, new_rows)
+        with jax.named_scope(spans.SCATTER):
+            store = tree_scatter(store, slots, new_rows)
         return (out.server, store), out.metrics
 
     xs = {

@@ -77,6 +77,7 @@ from repro.core.local_solver import (
     resolve_local_solver,
 )
 from repro.core.privatizer import get_privatizer, resolve_privatizer
+from repro.core import spans
 from repro.core.rounds import run_round
 from repro.core.sampling import (
     ClientSampler,
@@ -120,11 +121,13 @@ def make_grad_fn(loss_fn: Callable, *, space=None, spec=None,
     if space is not None and space.trains_subset:
 
         def grad_fn(deltas, batch):
-            full = space.apply(spec, base_params, deltas)
+            with jax.named_scope(spans.APPLY):
+                full = space.apply(spec, base_params, deltas)
             (loss, metrics), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(full, batch)
-            return space.grad_project(spec, base_params, deltas, grads), \
-                metrics
+            with jax.named_scope(spans.GRAD_PROJECT):
+                return space.grad_project(spec, base_params, deltas,
+                                          grads), metrics
 
         grad_fn.megakernel_grad = None
         return grad_fn
@@ -820,37 +823,44 @@ class FederatedTrainer:
 
     def _run_scan_chunk(self, R: int):
         """Execute R rounds as one on-device scan; returns the R per-round
-        metric dicts (also appended to ``history``)."""
-        if self._tiered_scan:
-            metrics = self._run_tiered_chunk(R)
-        else:
-            server, store, metrics = self._scan_fn(
-                self.server, self.device_store, self._device_data,
-                self.device_sampler.key, self._data_base_key,
-                self._comp_base_key if self._comp_keyed else None,
-                self._priv_base_key if self._priv_active else None,
-                self._device_sizes, self.round_idx, R, self.base_params)
-            self.server, self.device_store = server, store
-            self._host_store_dirty = True
-        stacked = {k: np.asarray(v) for k, v in metrics.items()}
-        out = []
-        for r in range(R):
-            self.round_idx += 1
-            m = {k: float(v[r]) for k, v in stacked.items()}
-            m.update(self._comm_bytes)  # exact ints over the fp32 metrics
-            if self._priv_active:
-                # exact float64 accountant over the fp32 device metric
-                m["dp_epsilon"] = self.privatizer.epsilon(
-                    self.spec, self.round_idx)
-            if self.megakernel_fallback_reason is not None:
-                m["megakernel_fallback_reason"] = (
-                    self.megakernel_fallback_reason)
-            if self.update_space.trains_subset:
-                m["update_space"] = self.update_space.name
-            m["round"] = self.round_idx
-            self.history.append(m)
-            out.append(m)
-        return out
+        metric dicts (also appended to ``history``). Host spans
+        (``core/spans.py``): the chunk, its dispatch and the metrics fetch;
+        the chunk's own time beyond the two is the history bookkeeping."""
+        with jax.profiler.TraceAnnotation(spans.CHUNK, rounds=R,
+                                          round=self.round_idx):
+            with jax.profiler.TraceAnnotation(spans.DISPATCH):
+                if self._tiered_scan:
+                    metrics = self._run_tiered_chunk(R)
+                else:
+                    server, store, metrics = self._scan_fn(
+                        self.server, self.device_store, self._device_data,
+                        self.device_sampler.key, self._data_base_key,
+                        self._comp_base_key if self._comp_keyed else None,
+                        self._priv_base_key if self._priv_active else None,
+                        self._device_sizes, self.round_idx, R,
+                        self.base_params)
+                    self.server, self.device_store = server, store
+                    self._host_store_dirty = True
+            with jax.profiler.TraceAnnotation(spans.FETCH):
+                stacked = {k: np.asarray(v) for k, v in metrics.items()}
+            out = []
+            for r in range(R):
+                self.round_idx += 1
+                m = {k: float(v[r]) for k, v in stacked.items()}
+                m.update(self._comm_bytes)  # exact ints over the fp32 metrics
+                if self._priv_active:
+                    # exact float64 accountant over the fp32 device metric
+                    m["dp_epsilon"] = self.privatizer.epsilon(
+                        self.spec, self.round_idx)
+                if self.megakernel_fallback_reason is not None:
+                    m["megakernel_fallback_reason"] = (
+                        self.megakernel_fallback_reason)
+                if self.update_space.trains_subset:
+                    m["update_space"] = self.update_space.name
+                m["round"] = self.round_idx
+                self.history.append(m)
+                out.append(m)
+            return out
 
     # ------------------------------------------------------------------
     # round loop

@@ -62,6 +62,7 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import spans
 from repro.util import uscan
 
 
@@ -444,9 +445,10 @@ def run_local_steps(
     if getattr(spec, "use_megakernel", False) and megakernel_incompatibility(
             grad_fn, solver, prox_mu=prox_mu, params=y0,
             batches=batches) is None:
-        return _run_megakernel_steps(
-            spec, y0, batches, solver=solver, slots=slots,
-            correction=correction, shard_fn=shard_fn, k_steps=k_steps)
+        with jax.named_scope(spans.LOCAL_STEP):
+            return _run_megakernel_steps(
+                spec, y0, batches, solver=solver, slots=slots,
+                correction=correction, shard_fn=shard_fn, k_steps=k_steps)
 
     def step(carry, batch):
         y, sl, t = carry
@@ -458,8 +460,9 @@ def run_local_steps(
                              - xx.astype(jnp.float32)),
                 grads, y, prox_center,
             )
-        y_new, sl_new = solver.step(spec, sl, y, grads, correction, t,
-                                    use_fused_update=use_fused_update)
+        with jax.named_scope(spans.SOLVER_STEP):
+            y_new, sl_new = solver.step(spec, sl, y, grads, correction, t,
+                                        use_fused_update=use_fused_update)
         if shard_fn is not None:
             # pin the whole param-sized carry, slots included — an
             # unpinned carry lets GSPMD replicate model-sized state per
@@ -469,8 +472,9 @@ def run_local_steps(
             sl_new = solver.shard_slots(shard_fn, sl_new)
         return (y_new, sl_new, t + 1), metrics["loss"]
 
-    (y, slots, _), losses = uscan(
-        step, (y0, slots, jnp.zeros((), jnp.int32)), batches)
+    with jax.named_scope(spans.LOCAL_STEP):  # names the ops of every step
+        (y, slots, _), losses = uscan(
+            step, (y0, slots, jnp.zeros((), jnp.int32)), batches)
     return y, slots, jnp.mean(losses)
 
 

@@ -67,6 +67,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core import spans
 from repro.core.api import (
     ClientRoundState,
     RoundOutput,
@@ -116,23 +117,25 @@ def client_update(grad_fn, spec, x, c, c_i, batches, solver_slots=None,
     compression of dy happens at the ``run_round`` level, shared by both
     client strategies.
     """
-    algo = get_algorithm(spec.algorithm)
-    correction = algo.local_correction(spec, x, c, c_i)
-    prox_mu = algo.prox_mu(spec)
-    prox_center = x if prox_mu else None
+    with jax.named_scope(spans.CLIENT):
+        algo = get_algorithm(spec.algorithm)
+        correction = algo.local_correction(spec, x, c, c_i)
+        prox_mu = algo.prox_mu(spec)
+        prox_center = x if prox_mu else None
 
-    y, slots_new, loss = run_local_steps(
-        grad_fn, spec, x, batches,
-        slots=solver_slots, correction=correction,
-        prox_mu=prox_mu, prox_center=prox_center,
-        use_fused_update=use_fused_update, shard_fn=shard_fn,
-    )
-    dy = tree_sub(y, x)
+        y, slots_new, loss = run_local_steps(
+            grad_fn, spec, x, batches,
+            slots=solver_slots, correction=correction,
+            prox_mu=prox_mu, prox_center=prox_center,
+            use_fused_update=use_fused_update, shard_fn=shard_fn,
+        )
+        dy = tree_sub(y, x)
 
-    c_i_new, dc = algo.client_control_update(
-        spec, x, y, c, c_i,
-        lambda: grad_fn(x, _merge_step_batches(batches))[0],
-    )
+        with jax.named_scope(spans.CONTROL):
+            c_i_new, dc = algo.client_control_update(
+                spec, x, y, c, c_i,
+                lambda: grad_fn(x, _merge_step_batches(batches))[0],
+            )
     return dy, dc, c_i_new, slots_new, loss
 
 
@@ -142,16 +145,17 @@ def _whole_batch_round(grad_fn, spec, server, clients, batches) -> RoundOutput:
     (``FedRoundSpec.__post_init__`` rejects those combinations loudly)."""
     flat = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[3:]), batches)
     grads, metrics = grad_fn(server.x, flat)
-    x_new = jax.tree.map(
-        lambda xx, gg: (xx - spec.eta_l * gg).astype(xx.dtype),
-        server.x, grads,
-    )
-    out_metrics = {
-        "loss": metrics["loss"],
-        "drift": jnp.zeros((), jnp.float32),
-        "update_norm": tree_norm(tree_sub(x_new, server.x)),
-        **_bytes_metrics(spec, server.x, stateful_clients=False),
-    }
+    with jax.named_scope(spans.SERVER):
+        x_new = jax.tree.map(
+            lambda xx, gg: (xx - spec.eta_l * gg).astype(xx.dtype),
+            server.x, grads,
+        )
+        out_metrics = {
+            "loss": metrics["loss"],
+            "drift": jnp.zeros((), jnp.float32),
+            "update_norm": tree_norm(tree_sub(x_new, server.x)),
+            **_bytes_metrics(spec, server.x, stateful_clients=False),
+        }
     return RoundOutput(
         server=dataclasses.replace(server, x=x_new),
         clients=clients,
@@ -270,12 +274,14 @@ def run_round(grad_fn, spec, server: ServerState, clients: ClientRoundState,
         wnorm = wnorm / jnp.maximum(wnorm.sum(), 1e-12)
 
     def _wmean(tree_stacked):
-        if weights is None:
-            return tree_mean_leading(tree_stacked)
-        return jax.tree.map(
-            lambda a: jnp.tensordot(
-                wnorm, a.astype(jnp.float32), axes=(0, 0)).astype(a.dtype),
-            tree_stacked)
+        with jax.named_scope(spans.AGGREGATE):
+            if weights is None:
+                return tree_mean_leading(tree_stacked)
+            return jax.tree.map(
+                lambda a: jnp.tensordot(
+                    wnorm, a.astype(jnp.float32), axes=(0, 0)
+                ).astype(a.dtype),
+                tree_stacked)
 
     def _res0(dy_like):
         """The carried residuals, or the codec's fresh ones (leaves match
@@ -346,10 +352,11 @@ def run_round(grad_fn, spec, server: ServerState, clients: ClientRoundState,
                          else None)
                 dy_k, res_new_k = up.round_trip(spec, dy_k, inp["res"],
                                                 key=key_k)
-            dy_acc = jax.tree.map(
-                lambda a, d: a + w_k * d.astype(a.dtype), dy_acc, dy_k)
-            dc_acc = jax.tree.map(
-                lambda a, d: a + w_k * d.astype(a.dtype), dc_acc, dc_k)
+            with jax.named_scope(spans.AGGREGATE):
+                dy_acc = jax.tree.map(
+                    lambda a, d: a + w_k * d.astype(a.dtype), dy_acc, dy_k)
+                dc_acc = jax.tree.map(
+                    lambda a, d: a + w_k * d.astype(a.dtype), dc_acc, dc_k)
             if shard_fn is not None:
                 dy_acc = shard_fn(dy_acc)
                 dc_acc = shard_fn(dc_acc)
@@ -395,34 +402,35 @@ def run_round(grad_fn, spec, server: ServerState, clients: ClientRoundState,
         loss = loss_sum / s
         drift = tree_norm(dy_mean)
 
-    # trusted-aggregator noise lands on the aggregated mean, after the
-    # codec round-trip and before the server optimizer sees it
-    if privatizing and priv.noise_at == "server":
-        dy_mean = priv.server_noise(
-            spec, dy_mean, jax.random.fold_in(priv_key, 1))
+    with jax.named_scope(spans.SERVER):
+        # trusted-aggregator noise lands on the aggregated mean, after the
+        # codec round-trip and before the server optimizer sees it
+        if privatizing and priv.noise_at == "server":
+            dy_mean = priv.server_noise(
+                spec, dy_mean, jax.random.fold_in(priv_key, 1))
 
-    # server update (eq. 5 / alg. 1 line 16-17) through the registered
-    # server optimizer (sgd / heavy-ball momentum / FedAdam), applied to
-    # the server's *exact* x (the downlink codec only perturbs what the
-    # clients see)
-    opt = get_server_optimizer(resolve_server_optimizer(spec))
-    x_new, opt_state_new, applied = opt.apply(
-        spec, server.opt_state, x, dy_mean)
-    c_new = algo.server_control_update(spec, c, dc_mean)
-    metrics = {
-        "loss": loss,
-        "drift": drift,
-        "update_norm": tree_norm(applied),
-        **_bytes_metrics(spec, x, stateful_clients=algo.stateful_clients),
-    }
-    if privatizing:
-        # fp32 so they scan-stack like every metric; the engines
-        # overwrite history's dp_epsilon with the exact float64
-        # accountant, the same discipline as the bytes metrics
-        metrics["dp_epsilon"] = priv.epsilon_traced(
-            spec, jnp.asarray(dp_round, jnp.float32) + 1.0)
-        if clipped_frac is not None:
-            metrics["dp_clipped_frac"] = clipped_frac
+        # server update (eq. 5 / alg. 1 line 16-17) through the registered
+        # server optimizer (sgd / heavy-ball momentum / FedAdam), applied
+        # to the server's *exact* x (the downlink codec only perturbs what
+        # the clients see)
+        opt = get_server_optimizer(resolve_server_optimizer(spec))
+        x_new, opt_state_new, applied = opt.apply(
+            spec, server.opt_state, x, dy_mean)
+        c_new = algo.server_control_update(spec, c, dc_mean)
+        metrics = {
+            "loss": loss,
+            "drift": drift,
+            "update_norm": tree_norm(applied),
+            **_bytes_metrics(spec, x, stateful_clients=algo.stateful_clients),
+        }
+        if privatizing:
+            # fp32 so they scan-stack like every metric; the engines
+            # overwrite history's dp_epsilon with the exact float64
+            # accountant, the same discipline as the bytes metrics
+            metrics["dp_epsilon"] = priv.epsilon_traced(
+                spec, jnp.asarray(dp_round, jnp.float32) + 1.0)
+            if clipped_frac is not None:
+                metrics["dp_clipped_frac"] = clipped_frac
     return RoundOutput(
         server=ServerState(x=x_new, c=c_new, opt_state=opt_state_new),
         clients=ClientRoundState(c_i=c_i_new,

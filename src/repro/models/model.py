@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.models import layers as L
 from repro.models import transformer as T
 
@@ -85,8 +86,9 @@ def forward_hidden(cfg, params, batch) -> Tuple[jnp.ndarray, jnp.ndarray]:
         prefix_len = cfg.num_prefix_tokens
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    x, aux = T.apply_stack(cfg, params["layers"], x, positions,
-                           prefix_len=prefix_len, enc_out=enc_out)
+    with jax.named_scope(spans.MODEL_BLOCKS):
+        x, aux = T.apply_stack(cfg, params["layers"], x, positions,
+                               prefix_len=prefix_len, enc_out=enc_out)
     x = L.apply_norm(cfg, x, params["ln_final"])
     if cfg.num_prefix_tokens:
         x = x[:, cfg.num_prefix_tokens:]
@@ -162,16 +164,16 @@ def loss_fn(cfg, params, batch) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     mask = (labels >= 0).astype(jnp.float32)
     labels = jnp.maximum(labels, 0)
     denom = jnp.maximum(mask.sum(), 1.0)
-    if cfg.loss_chunk_vocab:
-        hidden, aux = forward_hidden(cfg, params, batch)
-        nll = _chunked_ce(cfg, params, hidden, labels, mask)
-        loss = nll.sum() / denom
-    else:
-        logits, aux = forward(cfg, params, batch)
-        logits = logits.astype(jnp.float32)
-        logz = jax.scipy.special.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-        nll = (logz - gold) * mask
+    hidden, aux = forward_hidden(cfg, params, batch)
+    with jax.named_scope(spans.MODEL_HEAD):
+        if cfg.loss_chunk_vocab:
+            nll = _chunked_ce(cfg, params, hidden, labels, mask)
+        else:
+            logits = _unembed(cfg, params, hidden).astype(jnp.float32)
+            logz = jax.scipy.special.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(
+                logits, labels[..., None], axis=-1)[..., 0]
+            nll = (logz - gold) * mask
         loss = nll.sum() / denom
     if cfg.moe is not None:
         loss = loss + cfg.moe.router_aux_coef * aux
