@@ -9,9 +9,10 @@ Conventions:
 Long-sequence attention uses a kv-block-chunked online-softmax path
 (``flash_attention_jnp``) so that lowering at 32k/500k never materialises an
 (S, S) score matrix; sliding-window attention uses a banded two-block path
-(``local_attention_jnp``) that is O(S*W). The Pallas TPU kernels in
-``repro.kernels`` implement the same contracts for the hot paths and are
-validated against these references.
+(``local_attention_jnp``) that is O(S*W). On a TPU, W layers run the
+splash-attention kernel behind ``repro.kernels.swa_attention`` instead,
+where their shapes allow (``swa.takes``); these jnp cores are its
+references and the path everywhere else.
 """
 from __future__ import annotations
 
@@ -23,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.core import spans
+from repro.kernels.swa_attention import ops as swa
 from repro.util import umap, uscan
 
 # ---------------------------------------------------------------------------
@@ -304,20 +307,23 @@ def attention_block(cfg, p, x, positions, *, kind: str, prefix_len: int = 0,
     v = (x @ p["wv"]).reshape(b, s, hkv, d)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
-    if kind == "W":
-        w = cfg.sliding_window
-        if s % w == 0 and s >= 2 * w:
-            out = local_attention_jnp(q, k, v, window=w)
+    with jax.named_scope(spans.ATTENTION):
+        if kind == "W":
+            w = cfg.sliding_window
+            if swa.takes(s, w):
+                out = swa.swa_attention(q, k, v, w)
+            elif s % w == 0 and s >= 2 * w:
+                out = local_attention_jnp(q, k, v, window=w)
+            else:
+                out = dense_attention(q, k, v, mask_kind="sliding", window=w)
         else:
-            out = dense_attention(q, k, v, mask_kind="sliding", window=w)
-    else:
-        mask_kind = "prefix" if prefix_len else "causal"
-        if s > use_flash_threshold:
-            out = flash_attention_jnp(q, k, v, mask_kind=mask_kind,
+            mask_kind = "prefix" if prefix_len else "causal"
+            if s > use_flash_threshold:
+                out = flash_attention_jnp(q, k, v, mask_kind=mask_kind,
+                                          prefix_len=prefix_len)
+            else:
+                out = dense_attention(q, k, v, mask_kind=mask_kind,
                                       prefix_len=prefix_len)
-        else:
-            out = dense_attention(q, k, v, mask_kind=mask_kind,
-                                  prefix_len=prefix_len)
     return out.reshape(b, s, h * d) @ p["wo"]
 
 

@@ -1,6 +1,7 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles,
 executed in interpret mode (kernel body runs on CPU)."""
 import contextlib
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -394,3 +395,96 @@ def test_swa_matches_model_layer_semantics():
     out_model = dense_attention(q, k, v, mask_kind="sliding", window=w)
     out_kernel = swa_attention(q, k, v, w, interpret=True)
     assert float(jnp.max(jnp.abs(out_model - out_kernel))) < 2e-5
+
+
+# (B, S, Hq, Hkv, D, window): hymba's 5:1 GQA with window = 2 x block
+# (block_size(640, 256) == 128), and a batch of two at 2:1
+SWA_GRAD_CASES = [(1, 640, 5, 1, 64, 256), (2, 256, 4, 2, 64, 128)]
+
+
+def _swa_inputs(case, dtype):
+    b, s, hq, hkv, d, w = case
+    ks = jax.random.split(jax.random.key(s + w), 4)
+    return (jax.random.normal(ks[0], (b, s, hq, d), dtype),
+            jax.random.normal(ks[1], (b, s, hkv, d), dtype),
+            jax.random.normal(ks[2], (b, s, hkv, d), dtype),
+            jax.random.normal(ks[3], (b, s, hq, d), dtype))
+
+
+def _assert_close(got, want, dtype):
+    """Each array within a share of the reference's largest entry."""
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    for g, r in zip(got, want):
+        assert g.shape == r.shape and g.dtype == dtype
+        r = r.astype(jnp.float32)
+        err = jnp.max(jnp.abs(g.astype(jnp.float32) - r)) / jnp.max(jnp.abs(r))
+        assert float(err) < tol
+
+
+@pytest.mark.parametrize("case", SWA_GRAD_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_swa_attention_grad_matches_dense(case, dtype):
+    """dq / dk / dv of the kernel's VJP == the model's dense sliding-window
+    attention differentiated in float32 on the same inputs."""
+    from repro.models.layers import dense_attention
+
+    w = case[-1]
+    q, k, v, g = _swa_inputs(case, dtype)
+    out, vjp = jax.vjp(lambda q, k, v: swa_attention(q, k, v, w,
+                                                     interpret=True), q, k, v)
+    f32 = partial(jnp.asarray, dtype=jnp.float32)
+    out_r, vjp_r = jax.vjp(
+        lambda q, k, v: dense_attention(q, k, v, mask_kind="sliding",
+                                        window=w), f32(q), f32(k), f32(v))
+    _assert_close((out, *vjp(g)), (out_r, *vjp_r(f32(g))), dtype)
+
+
+def _layer_stack_loss(attn, xs):
+    """The model's layer scan: each layer remat'd under ``jax.checkpoint``
+    with nothing saved, as ``transformer.apply_stack`` runs it."""
+    def body(carry, layer):
+        q, k, v = layer
+        out = jax.checkpoint(
+            attn, policy=jax.checkpoint_policies.nothing_saveable)(
+                q + carry, k, v)
+        return carry + out, None
+
+    carry, _ = jax.lax.scan(body, jnp.zeros_like(xs[0][0]), xs)
+    return jnp.sum(jnp.sin(carry))
+
+
+def test_swa_attention_grad_under_checkpoint_in_scan():
+    from repro.models.layers import dense_attention
+
+    b, s, hq, hkv, d, w = SWA_GRAD_CASES[0]
+    layers = 2
+    ks = jax.random.split(jax.random.key(7), 3)
+    xs = (jax.random.normal(ks[0], (layers, b, s, hq, d)),
+          jax.random.normal(ks[1], (layers, b, s, hkv, d)),
+          jax.random.normal(ks[2], (layers, b, s, hkv, d)))
+    kern = partial(swa_attention, window=w, interpret=True)
+    dense = partial(dense_attention, mask_kind="sliding", window=w)
+    got = jax.value_and_grad(partial(_layer_stack_loss, kern))(xs)
+    want = jax.value_and_grad(partial(_layer_stack_loss, dense))(xs)
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
+    _assert_close(got[1], want[1], jnp.float32)
+
+
+def test_swa_attention_grad_under_vmap():
+    """Clients in parallel (``client_parallel``): the op under ``jax.vmap``."""
+    from repro.models.layers import dense_attention
+
+    case = SWA_GRAD_CASES[1]
+    w = case[-1]
+    q, k, v, g = _swa_inputs(case, jnp.float32)  # batch axis as 2 clients
+    q, k, v, g = (a[:, None] for a in (q, k, v, g))
+
+    def grads(attn):
+        def one(q, k, v, g):
+            out, vjp = jax.vjp(attn, q, k, v)
+            return (out, *vjp(g))
+        return jax.vmap(one)(q, k, v, g)
+
+    _assert_close(grads(partial(swa_attention, window=w, interpret=True)),
+                  grads(partial(dense_attention, mask_kind="sliding",
+                                window=w)), jnp.float32)

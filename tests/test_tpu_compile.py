@@ -11,7 +11,9 @@ The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and the suite runs
 under several workers that all import this file.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +21,7 @@ import pytest
 
 from repro.kernels.scaffold_update import megakernel as mk
 from repro.kernels.scaffold_update import ops
-from repro.kernels.swa_attention.kernel import swa_attention_bhsd
+from repro.kernels.swa_attention import ops as swa
 
 # hymba-1.5b's MLP gate stack: 32 layers of (d_model 1600, d_ff 5504)
 STACK = (32, 1600, 5504)
@@ -116,13 +118,61 @@ def test_megakernel_compiles(solver, d, one_chip, as_tpu):
     _assert_kernel(compiled)
 
 
-def test_swa_attention_compiles_hymba_shapes(one_chip, no_persistent_cache):
+@pytest.fixture
+def swa_as_tpu(monkeypatch, no_persistent_cache):
+    monkeypatch.setattr(swa, "_is_tpu", lambda: True)
+
+
+def _kernel_op_names(text):
+    """The ``op_name`` of every Mosaic kernel in a compiled program."""
+    return re.findall(r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"',
+                      text, re.S)
+
+
+def test_swa_attention_compiles_hymba_shapes(one_chip, swa_as_tpu):
     """hymba-1.5b: 25 query / 5 kv heads of width 64, window 1024, at a
-    2048-token sequence."""
+    2048-token sequence; value and gradient under ``jax.checkpoint``, as
+    the model's remat'd layer scan takes them: the forward kernel, then
+    in the backward the remat'd forward and the fused dq / dkv kernel."""
     def sds(shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
-    compiled = _compile(
-        lambda q, k, v: swa_attention_bhsd(q, k, v, 1024),
-        sds((1, 25, 2048, 64)), sds((1, 5, 2048, 64)), sds((1, 5, 2048, 64)))
-    _assert_kernel(compiled)
+    def loss(q, k, v):
+        out = jax.checkpoint(lambda q, k, v: swa.swa_attention(q, k, v, 1024))(
+            q, k, v)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                        sds((1, 2048, 25, 64)), sds((1, 2048, 5, 64)),
+                        sds((1, 2048, 5, 64)))
+    names = _kernel_op_names(compiled.as_text())
+    forward = [n for n in names if "transpose(" not in n]
+    backward = [n for n in names if "transpose(" in n]
+    assert any("splash_mha_fwd" in n for n in forward), names
+    for phase in ("splash_mha_fwd", "splash_mha_dkv"):
+        assert any(phase in n for n in backward), (phase, names)
+
+
+@pytest.mark.parametrize("window,seq,kernel", [
+    (128, 256, True), (128, 384, True),
+    (128, 200, False),  # seq not a multiple of 128: dense sliding
+    (128, 128, False),  # seq < 2 * window: dense sliding
+    (64, 256, False),   # window not a multiple of 128: the jnp band
+])
+def test_window_layer_selects_kernel_by_shape(window, seq, kernel, one_chip,
+                                              swa_as_tpu):
+    from repro.configs import get_reduced
+    from repro.models import layers as L
+
+    cfg = dataclasses.replace(get_reduced("hymba-1.5b"),
+                              sliding_window=window)
+    p = jax.eval_shape(lambda: L.init_attention(cfg, jax.random.key(0),
+                                                jnp.float32))
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                    sharding=one_chip), p)
+    x = jax.ShapeDtypeStruct((1, seq, cfg.d_model), jnp.float32,
+                             sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((1, seq), jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda p, x, pos: L.attention_block(
+        cfg, p, x, pos, kind="W")).lower(p, x, pos).as_text()
+    assert ("tpu_custom_call" in text) == kernel
