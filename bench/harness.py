@@ -3,9 +3,11 @@ check what its timed path produced against the plain reference.
 
 Everything a cell needs is found by name: the workload in
 ``BENCHMARK.json``, its configuration file, ``bench/traffic/<mix>.json``
-(read by the generator it names), ``bench/limits/<workload>.json`` and a
-reader ``bench/metrics/<metric>.py`` per per-layer metric. A new cell is
-new files and entries, never an edit here.
+(read by the generator it names), ``bench/limits/<workload>.json``, a
+reader ``bench/metrics/<metric>.py`` per per-layer metric, and a module
+``bench/kinds/<kind>.py`` per kind of layer in the configuration's stack
+(``layer_groups``). A new cell is new files and entries, never an edit
+here.
 
 The timed object is the program's ``FederatedTrainer`` on its scanned
 engine and dense store, built as users build it. Set-up gives it the
@@ -18,6 +20,7 @@ program freed, does the reference follow that first round.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -76,31 +79,71 @@ def per_layer_metrics(name: str, benchmark: Dict) -> List[Dict]:
             and m["moves"] in reported]
 
 
+# ------------------------------------------------------------ layer kinds
+
+
+def layer_groups(shapes: Dict) -> List[Dict]:
+    """The configuration's layer stack, ``[{"kind": ..., "count": n}]`` in
+    the program's layer order: ``shapes["groups"]``, or one group of
+    ``shapes["layers"]`` layers of ``shapes["layer_kind"]``."""
+    if "groups" in shapes:
+        return shapes["groups"]
+    return [{"kind": shapes["layer_kind"], "count": shapes["layers"]}]
+
+
+@functools.lru_cache(maxsize=None)
+def layer_kind(name: str):
+    """The module ``bench/kinds/<name>.py`` of a kind of layer. It gives
+    ``LETTER`` and ``USES_MOE``, the program's layer signature it stands
+    for; ``program_shapes(cfg)``, its ``shapes`` keys as the program's
+    ``ModelConfig`` has them; ``layer(s, p, lin, x, quant, ssd)``, its
+    float32 reference layer on the residual stream; ``matmuls(s)``, its
+    weight matmuls ``(leaf, in, out)``; and ``other_flops(s, seq)``, its
+    other forward FLOPs on one sequence."""
+    return load_module(BENCH / "kinds" / f"{name}.py")
+
+
+def ssm_shapes(cfg) -> Dict:
+    """The SSD keys of ``shapes`` from the program's ``cfg.ssm``, for the
+    kinds whose layers hold SSD heads."""
+    return {"ssm_d_state": cfg.ssm.d_state, "ssm_head_dim": cfg.ssm.head_dim,
+            "ssm_expand": cfg.ssm.expand, "ssm_conv": cfg.ssm.conv_kernel}
+
+
 # --------------------------------------------------------------- program
 
 
 def program_model(config: Dict):
     """The program's ``ModelConfig`` for a configuration file, checked
-    against the file's ``shapes``."""
-    from repro.configs import SSMConfig, get_config
+    against the file's ``shapes``: its layer groups (kind, MoE, count)
+    against ``transformer.layer_groups``, and each kind's keys. An
+    override that is a dict replaces those fields of a nested config
+    (``ssm``, ``moe``), or makes one where the arch has none."""
+    from repro.configs import MoEConfig, SSMConfig, get_config
+    from repro.models.transformer import layer_groups as program_groups
 
     cfg = get_config(config["program"]["arch"])
     over = dict(config["program"].get("overrides", {}))
-    if isinstance(over.get("ssm"), dict):
-        over["ssm"] = SSMConfig(**over["ssm"])
+    nested = {"ssm": SSMConfig, "moe": MoEConfig}
+    for k, v in over.items():
+        if isinstance(v, dict):
+            cur = getattr(cfg, k)
+            over[k] = nested[k](**v) if cur is None else dataclasses.replace(cur, **v)
     cfg = dataclasses.replace(cfg, **over)
     s = config["shapes"]
-    want = {"layers": cfg.num_layers, "d_model": cfg.d_model,
-            "vocab": cfg.vocab_size, "ssm_d_state": cfg.ssm.d_state,
-            "ssm_head_dim": cfg.ssm.head_dim, "ssm_expand": cfg.ssm.expand,
-            "ssm_conv": cfg.ssm.conv_kernel}
-    if s["layer_kind"] == "Y":
-        want.update(n_heads=cfg.num_heads, n_kv_heads=cfg.num_kv_heads,
-                    head_dim=cfg.head_dim, d_ff=cfg.d_ff,
-                    window=cfg.sliding_window, rope_theta=cfg.rope_theta)
+    groups = layer_groups(s)
+    want = {"d_model": cfg.d_model, "vocab": cfg.vocab_size}
+    if "layers" in s:
+        want["layers"] = cfg.num_layers
+    for g in groups:
+        want.update(layer_kind(g["kind"]).program_shapes(cfg))
     bad = {k: (s.get(k), v) for k, v in want.items() if s.get(k) != v}
-    if bad or cfg.pattern_for_layers() != s["layer_kind"] * s["layers"]:
-        raise ValueError(f"configuration shapes disagree with the program: {bad}")
+    have = [(g.kind, g.uses_moe, g.count, g.has_cross) for g in program_groups(cfg)]
+    need = [(layer_kind(g["kind"]).LETTER, layer_kind(g["kind"]).USES_MOE,
+             g["count"], False) for g in groups]
+    if bad or have != need:
+        raise ValueError(f"configuration shapes disagree with the program: {bad}; "
+                         f"layer groups {need} against the program's {have}")
     return cfg
 
 

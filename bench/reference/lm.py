@@ -1,18 +1,16 @@
-"""Plain float32 reference of the benchmark's two layer kinds and the loss.
+"""Plain float32 reference: the shared maths of the benchmark's layer
+kinds, and the loss over a configuration's stack of layer groups.
 
 Written from the published layer equations, in ``jax.numpy`` with no
 kernels, and run under ``jax.default_matmul_precision("highest")`` (the
 caller sets it). It imports nothing of the program; it reads the program's
 parameter layout (a dict per layer group, leaves stacked on a leading
 layer axis) as data, and its sizes from the ``shapes`` block of the
-benchmark's configuration file.
-
-  Y  (hymba, arXiv:2411.13676): h = RMSNorm(x);
-     x += 0.5 * (SWA-GQA-attention(h) + SSD(h)); x += SwiGLU(RMSNorm(x)).
-     Departures kept from the program: sliding-window attention in every
-     layer, no meta tokens, no KV sharing, SSD heads (configuration file,
-     ``assumed``).
-  M  (Mamba-2, arXiv:2405.21060): x += SSD-block(RMSNorm(x)).
+benchmark's configuration file. Each kind of layer is a module
+``bench/kinds/<kind>.py`` that builds its layer from the pieces here
+(``rms_norm``, ``linear``, ``rope``, ``attention``, ``ssd_chunked``,
+``mamba``, ``mlp``); ``loss`` finds the kinds by the names in the
+configuration's groups (``harness.layer_groups``).
 
 The SSD scan is y_t = sum_{s<=t} (C_t . B_s) exp(sum_{r=s+1..t} dt_r a)
 dt_s x_s + D x_t, computed in chunks as the paper's minimal listing
@@ -36,6 +34,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+import harness
 
 f32 = jnp.float32
 EPS = 1e-6
@@ -94,22 +94,27 @@ def linear(p, d, scale, quant):
     return lin
 
 
-def attention(s, lin, h, quant):
-    """Causal sliding-window GQA with RoPE; q head i reads kv head
-    i // (n_heads / n_kv_heads)."""
+def attention(s, lin, h, quant, *, rope_theta=None, window=None, scale=None):
+    """Causal GQA; q head i reads kv head i // (n_heads / n_kv_heads).
+    RoPE on q and k at ``rope_theta`` where given, keys within the last
+    ``window`` positions where given, scores scaled by ``scale``, else by
+    1 / sqrt(head_dim)."""
     b, t, _ = h.shape
     nh, nkv, hd = s["n_heads"], s["n_kv_heads"], s["head_dim"]
     pos = jnp.arange(t)
-    q = rope(lin("attn.wq", h).reshape(b, t, nh, hd), pos, s["rope_theta"])
-    k = rope(lin("attn.wk", h).reshape(b, t, nkv, hd), pos, s["rope_theta"])
+    pe = (lambda u: u) if rope_theta is None else (lambda u: rope(u, pos, rope_theta))
+    q = pe(lin("attn.wq", h).reshape(b, t, nh, hd))
+    k = pe(lin("attn.wk", h).reshape(b, t, nkv, hd))
     v = lin("attn.wv", h).reshape(b, t, nkv, hd)
     k = jnp.repeat(k, nh // nkv, axis=2)
     v = jnp.repeat(v, nh // nkv, axis=2)
     scores = jnp.einsum("bqhd,bkhd->bhqk", _q(q, quant), _q(k, quant))
-    scores = scores / math.sqrt(hd)
+    scores = scores / math.sqrt(hd) if scale is None else scores * scale
     rel = pos[:, None] - pos[None, :]
-    band = (rel >= 0) & (rel < s["window"])
-    scores = jnp.where(band[None, None], scores, -jnp.inf)
+    mask = rel >= 0
+    if window is not None:
+        mask = mask & (rel < window)
+    scores = jnp.where(mask[None, None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", _q(probs, quant), _q(v, quant))
     return lin("attn.wo", out.reshape(b, t, nh * hd))
@@ -195,53 +200,54 @@ def mlp(lin, h):
     return lin("mlp.w_down", jax.nn.silu(lin("mlp.w_gate", h)) * lin("mlp.w_up", h))
 
 
-def layer(s, p, d, scale, x, quant, ssd=ssd_chunked):
-    """One layer of kind ``s["layer_kind"]`` on the residual stream x;
+def layer(kind, s, p, d, scale, x, quant, ssd=ssd_chunked):
+    """One layer of the kind module ``kind`` on the residual stream x;
     ``d`` maps "attn.wq"-style paths to the layer's LoRA factors."""
     p = jax.tree.map(lambda w: w.astype(f32), p)
-    lin = linear(p, d, scale, quant)
-    if s["layer_kind"] == "Y":
-        h = rms_norm(x, p["ln_attn"]["scale"])
-        x = x + 0.5 * (attention(s, lin, h, quant)
-                       + mamba(s, p["mamba"], lin, h, quant, ssd))
-        return x + mlp(lin, rms_norm(x, p["ln_mlp"]["scale"]))
-    if s["layer_kind"] == "M":
-        return x + mamba(s, p["mamba"], lin, rms_norm(x, p["ln_attn"]["scale"]),
-                         quant, ssd)
-    raise ValueError(s["layer_kind"])
+    return kind.layer(s, p, linear(p, d, scale, quant), x, quant, ssd)
 
 
 def split_deltas(deltas):
-    """``{"layers.0.attn.wq": f}`` -> ``({"attn.wq": f}, top-level)``:
-    the per-layer factors of the stacked group and any others."""
-    per_layer, other = {}, {}
+    """``{"layers.1.attn.wq": f}`` -> ``({1: {"attn.wq": f}}, top-level)``:
+    the per-layer factors of each stacked group, by the group's index,
+    and any others."""
+    per_group, other = {}, {}
     for path, fac in deltas.items():
         parts = path.split(".")
         if parts[0] == "layers":
-            assert parts[1] == "0", f"one layer group expected, got {path}"
-            per_layer[".".join(parts[2:])] = fac
+            per_group.setdefault(int(parts[1]), {})[".".join(parts[2:])] = fac
         else:
             other[path] = fac
-    return per_layer, other
+    return per_group, other
 
 
 def loss(s, base, deltas, batch, scale, quant=None, ssd=ssd_chunked):
     """Mean next-token cross-entropy of the adapted model on ``batch``
-    (tokens/labels (B, T)). One scan over the layers, each recomputed in
-    the backward pass, so only the residual stream is kept per layer."""
-    per_layer, other = split_deltas(deltas)
+    (tokens/labels (B, T)). One scan over the layers of each group, each
+    layer recomputed in the backward pass, so only the residual stream is
+    kept per layer. ``s`` may give, under its source's names, a factor on
+    the embeddings (``embedding_multiplier``) and a divisor of the
+    logits (``logits_scaling``)."""
+    per_group, other = split_deltas(deltas)
     assert not other, f"LoRA on top-level leaves is not modelled: {list(other)}"
-    (group,) = base["layers"]
+    groups = harness.layer_groups(s)
+    assert len(groups) == len(base["layers"]), (groups, len(base["layers"]))
     x = base["embed"].astype(f32)[batch["tokens"]]
+    if "embedding_multiplier" in s:
+        x = x * s["embedding_multiplier"]
+    for g, (group, params) in enumerate(zip(groups, base["layers"])):
+        kind = harness.layer_kind(group["kind"])
 
-    @jax.checkpoint
-    def body(x, lp_ld):
-        lp, ld = lp_ld
-        return layer(s, lp, ld, scale, x, quant, ssd), None
+        @jax.checkpoint
+        def body(x, lp_ld, kind=kind):
+            lp, ld = lp_ld
+            return layer(kind, s, lp, ld, scale, x, quant, ssd), None
 
-    x, _ = jax.lax.scan(body, x, (group, per_layer))
+        x, _ = jax.lax.scan(body, x, (params, per_group.get(g, {})))
     x = rms_norm(x, base["ln_final"]["scale"])
     logits = mm(x, base["embed"].astype(f32).T, quant)
+    if "logits_scaling" in s:
+        logits = logits / s["logits_scaling"]
     logz = jax.scipy.special.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, batch["labels"][..., None], -1)[..., 0]
     return jnp.mean(logz - gold)

@@ -58,7 +58,9 @@ def device_report(jax, chips: int) -> dict:
 
 
 def traced_window(jax, trainer, seconds: float):
-    """The window under the profiler; its result and the trace summary."""
+    """The window under the profiler; its result and the trace summary,
+    with the span reduction under ``"spans"``."""
+    import spans
     import trace_reduce
 
     out_dir = tempfile.mkdtemp(prefix="bench-trace-")
@@ -73,6 +75,8 @@ def traced_window(jax, trainer, seconds: float):
         with jax.profiler.trace(out_dir, profiler_options=opts):
             win = harness.window(trainer, seconds)
         summary = trace_reduce.summarize(*trace_reduce.load(out_dir))
+        if summary:
+            summary["spans"] = spans.reduce(*spans.load(out_dir))
     finally:
         shutil.rmtree(out_dir, ignore_errors=True)
     return win, summary

@@ -379,7 +379,9 @@ def layer_metrics(red: Dict, rounds: int, steps: int) -> Dict[str, float]:
     """The per-layer numbers, in ms: device time per local step or per
     round under the scopes of each layer, idle time per local step
     between two ops of the local step, and host time per round in the
-    dispatch and in the fetch after the chunk's last device op."""
+    dispatch and in the fetch after the chunk's last device op; device
+    time per local step under ``model.attention`` only where an op
+    carries that scope."""
     if not red or rounds <= 0 or steps <= 0:
         return {}
     under = red["under"]
@@ -388,7 +390,7 @@ def layer_metrics(red: Dict, rounds: int, steps: int) -> Dict[str, float]:
         return 1e3 * sum(under.get(n, 0.0) for n in scopes) / count
 
     chunks = red["chunks"]
-    return {
+    out = {
         "local_step_dev_ms": per(steps, names.LOCAL_STEP),
         "step_idle_ms": 1e3 * red["idle_under"].get(names.LOCAL_STEP, 0.0) / steps,
         "merge_dev_ms": per(steps, names.APPLY),
@@ -399,6 +401,17 @@ def layer_metrics(red: Dict, rounds: int, steps: int) -> Dict[str, float]:
         "dispatch_ms": sum(c["dispatch_ms"] for c in chunks) / rounds,
         "fetch_ms": sum(c.get("fetch_after_last_op_ms", 0.0) for c in chunks) / rounds,
     }
+    if names.ATTENTION in under:
+        out["attention_dev_ms"] = per(steps, names.ATTENTION)
+    return out
+
+
+def window_metric(ctx, name: str) -> Optional[float]:
+    """``layer_metrics``' number ``name`` for a metric reader's context:
+    the traced window's span reduction (``ctx.trace["spans"]``) over its
+    rounds and their local steps; None where the trace has none."""
+    steps = ctx.rounds * ctx.traffic["num_sampled"] * ctx.traffic["local_steps"]
+    return layer_metrics(ctx.trace.get("spans", {}), ctx.rounds, steps).get(name)
 
 
 # ---------------------------------------------------------------- run

@@ -12,11 +12,14 @@ import run  # noqa: E402
 import tiny  # noqa: E402
 
 
-def run_tiny(config: str, traffic: str, seed: int = 2**31 + 11,
+def run_tiny(config, traffic: str, seed: int = 2**31 + 11,
              limits_of: str = "hymba-silo-2k") -> dict:
+    """``config``: a benchmark configuration's name, cut by ``tiny.config``,
+    or a tiny configuration itself."""
     benchmark = harness.load_json(harness.REPO / "BENCHMARK.json")
-    wl = {"name": f"tiny-{config}-{traffic}", "chips": 1}
+    conf = tiny.config(config) if isinstance(config, str) else config
+    wl = {"name": f"tiny-{conf['name']}-{traffic}", "chips": 1}
     limits = harness.resolve(limits_of, benchmark)[3]
-    files = (wl, tiny.config(config), tiny.traffic(traffic), limits)
+    files = (wl, conf, tiny.traffic(traffic), limits)
     return run.run_cell(wl["name"], seed, 0.05, False, benchmark, files,
                         t_start=time.perf_counter())

@@ -33,6 +33,42 @@ def test_hand_count():
     assert flops.required_per_round(HAND, traffic) == 10 * 3 * (2 * 2168 + 96)
 
 
+def test_hand_count_full_attention():
+    # F layer, per token: projections 32+16+16+32, MLP 3*48 -> 240;
+    # 3 tokens: 720 + head 2*4*5*3 = 120 + attention 16 * (1+2+3) = 96
+    # -> forward 936; LoRA on wq and w_down: 4*1*(4+4 + 6+4)*3 = 216
+    s = dict(HAND, layer_kind="F")
+    traffic = {"seq_len": 3, "local_batch": 1, "lora_rank": 1,
+               "num_sampled": 1, "local_steps": 1}
+    s["lora_targets"] = ["wq", "w_down"]
+    assert flops.forward_per_sequence(s, 3) == 936
+    assert flops.required_per_step(s, traffic) == 2 * 936 + 216
+
+
+def test_groups_sum_their_kinds():
+    """Two groups count as their kinds' layers each, with one LM head."""
+    s = dict(HAND, groups=[{"kind": "Y", "count": 2}, {"kind": "F", "count": 1},
+                           {"kind": "Y", "count": 1}])
+    del s["layer_kind"], s["layers"]
+    head = 2 * 4 * 5 * 3
+    y = flops.forward_per_sequence(dict(HAND, layers=3), 3)
+    f = flops.forward_per_sequence(dict(HAND, layer_kind="F"), 3)
+    assert flops.forward_per_sequence(s, 3) == y + f - head
+    assert flops.lora_grad_per_sequence(s, 3, 1) == 4 * 96
+
+
+# the counts of the configurations as committed, fixed so that ``mfu``
+# moves only with the window
+PINNED = {"hymba-1.5b": 279119617064960, "mamba2-2.7b": 242237497671680}
+
+
+@pytest.mark.parametrize("name", sorted(PINNED))
+def test_required_per_round_is_pinned(name):
+    shapes = harness.load_json(BENCH / "configs" / f"{name}.json")["shapes"]
+    traffic = harness.load_json(BENCH / "traffic" / "silo-2k.json")
+    assert flops.required_per_round(shapes, traffic) == PINNED[name]
+
+
 @pytest.mark.parametrize("name", ["hymba-1.5b", "mamba2-2.7b"])
 def test_required_within_compiled_step(name):
     """The program computes more than is required (dense dW, remat), so

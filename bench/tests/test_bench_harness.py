@@ -87,6 +87,43 @@ def test_new_cell_is_new_files_and_entries(tmp_path, benchmark):
     assert traffic2["seq_len"] == 64 and config["name"] == "mamba2-2.7b"
     assert len(harness.per_layer_metrics("mamba2-short-64", b)) == len(b["per_layer"])
 
+    # a configuration whose stack mixes kinds, as groups: a file and entries
+    hybrid = json.loads((BENCH / "configs" / "mamba2-2.7b.json").read_text())
+    hybrid["name"] = "hybrid-mf"
+    hybrid["program"]["overrides"].update(
+        layer_pattern="MMMF", num_layers=8, num_heads=20, num_kv_heads=4, head_dim=128,
+        d_ff=6912)
+    attn = {"n_heads": 20, "n_kv_heads": 4, "head_dim": 128, "d_ff": 6912,
+            "rope_theta": 10000.0}
+    s = hybrid["shapes"]
+    del s["layer_kind"], s["layers"]
+    s.update(attn, groups=[{"kind": "M", "count": 3}, {"kind": "F", "count": 1}] * 2)
+    (root / "bench" / "configs" / "hybrid-mf.json").write_text(json.dumps(hybrid))
+    (root / "bench" / "limits" / "hybrid-mf-short-64.json").write_text(
+        (BENCH / "limits" / "mamba2-silo-2k.json").read_text())
+    b["configs"].append({"name": "hybrid-mf", "source": "test",
+                         "file": "bench/configs/hybrid-mf.json", "reduced": [],
+                         "why": "test"})
+    b["workloads"].append({"name": "hybrid-mf-short-64", "config": "hybrid-mf",
+                           "traffic": "short-64", "chips": 1, "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(b))
+    _, config, traffic3, _ = harness.resolve("hybrid-mf-short-64", root=root)
+    cfg = harness.program_model(config)
+    assert cfg.pattern_for_layers() == "MMMFMMMF"
+    import flops
+    seq = traffic3["seq_len"]
+    one_group = {k: v for k, v in config["shapes"].items() if k != "groups"}
+    alone = lambda kind, n: flops.forward_per_sequence(  # noqa: E731
+        dict(one_group, layer_kind=kind, layers=n), seq)
+    head = 2 * s["d_model"] * s["vocab"] * seq
+    assert flops.forward_per_sequence(config["shapes"], seq) == (
+        alone("M", 6) + alone("F", 2) - head)
+    assert flops.required_per_round(config["shapes"], traffic3) > 0
+    # a group that disagrees with the program's stack is refused
+    config["shapes"]["groups"] = [{"kind": "M", "count": 6}, {"kind": "F", "count": 2}]
+    with pytest.raises(ValueError, match="layer groups"):
+        harness.program_model(config)
+
 
 def test_code_names_no_cell(benchmark):
     names = ({w["name"] for w in benchmark["workloads"]}
@@ -95,6 +132,31 @@ def test_code_names_no_cell(benchmark):
     for path in list(BENCH.glob("*.py")) + list(BENCH.glob("metrics/*.py")):
         text = path.read_text()
         assert not [n for n in names if n in text], path
+
+
+def test_nested_overrides_replace_fields():
+    """A dict override replaces the fields it names of the arch's nested
+    config, or makes one where there is none; MoE layers then no longer
+    match kinds that hold none."""
+    config = json.loads((BENCH / "configs" / "mamba2-2.7b.json").read_text())
+    config["program"]["overrides"]["ssm"] = {"chunk_size": 128}
+    cfg = harness.program_model(config)
+    assert (cfg.ssm.chunk_size, cfg.ssm.d_state, cfg.ssm.head_dim) == (128, 128, 64)
+    config["program"]["overrides"]["moe"] = {"num_experts": 9, "top_k": 2,
+                                             "expert_d_ff": 768}
+    with pytest.raises(ValueError, match="layer groups"):
+        harness.program_model(config)
+
+
+def test_code_names_no_layer_kind():
+    """The harness, the reference and the FLOP count find each kind by the
+    name a configuration's groups give: none names a kind itself."""
+    kinds = [p.stem for p in (BENCH / "kinds").glob("*.py")]
+    assert {"Y", "M", "F"} <= set(kinds)
+    for path in (BENCH / "harness.py", BENCH / "reference" / "lm.py", BENCH / "flops.py"):
+        text = path.read_text()
+        named = [k for k in kinds if re.search(rf"""(["']){re.escape(k)}\1""", text)]
+        assert not named, (path, named)
 
 
 def test_run_without_a_chip_exits_nonzero_with_no_result():
@@ -117,6 +179,11 @@ def test_readers_leave_out_what_they_cannot_read():
                                              .read_text()))
     read = lambda name: harness.load_module(BENCH / "metrics" / f"{name}.py").read(ctx)  # noqa: E731
     assert read("idle_share") is None and read("chunk_gap_ms") is None
+    spans = [m["name"] for m in json.loads((REPO / "BENCHMARK.json").read_text())["per_layer"]
+             if m["source"] in ("device_trace", "program_span")
+             and m["name"] not in ("idle_share", "chunk_gap_ms")]
+    assert len(spans) == 10
+    assert all(read(name) is None for name in spans)
     assert read("peak_hbm_gib") == 3.0
     import flops
     want = 100 * 2 * flops.required_per_round(ctx.shapes, ctx.traffic) / (10.0 * 197e12)

@@ -5,6 +5,7 @@ import subprocess
 import sys
 import time
 from pathlib import Path
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -118,6 +119,34 @@ def test_chunks_and_per_step_normalisation():
     assert m["data_dev_ms"] == pytest.approx(2e-6 / 2)
     assert m["dispatch_ms"] == pytest.approx(12e-6 / 2)
     assert m["fetch_ms"] == pytest.approx((11 + 9) * 1e-6 / 2)
+
+
+def test_attention_only_where_its_scope_ran():
+    devices, host = _synthetic()
+    assert "attention_dev_ms" not in spans.layer_metrics(spans.reduce(devices, host), 2, 4)
+    devices["d"].insert(-1, (f"{STEP}/jvp(model.blocks)/{names.ATTENTION}/dot", 160, 166))
+    m = spans.layer_metrics(spans.reduce(devices, host), rounds=2, steps=4)
+    assert m["attention_dev_ms"] == pytest.approx(6e-6 / 4)
+    assert m["local_step_dev_ms"] == pytest.approx(99e-6 / 4)
+
+
+def test_readers_read_the_window_reduction():
+    """Each span metric's reader gives ``layer_metrics``' number of the
+    traced window that ``bench/run.py`` hands it under ``trace["spans"]``."""
+    import harness
+
+    devices, host = _synthetic()
+    devices["d"].insert(-1, (f"{STEP}/jvp(model.blocks)/{names.ATTENTION}/dot", 160, 166))
+    red = spans.reduce(devices, host)
+    ctx = SimpleNamespace(trace={"busy_s": red["busy_s"], "spans": red}, rounds=2,
+                          traffic={"num_sampled": 2, "local_steps": 1})
+    want = spans.layer_metrics(red, rounds=2, steps=4)
+    assert len(want) == 10
+    for name, value in want.items():
+        assert harness.load_module(BENCH / "metrics" / f"{name}.py").read(ctx) == value
+    ctx.trace = {"busy_s": red["busy_s"]}
+    assert all(harness.load_module(BENCH / "metrics" / f"{name}.py").read(ctx) is None
+               for name in want)
 
 
 def test_run_as_a_script_finds_the_program_names():
