@@ -31,6 +31,7 @@ _ARCH_MODULES = {
     "mamba2-2.7b": "repro.configs.mamba2_2_7b",
     "qwen2-moe-a2.7b": "repro.configs.qwen2_moe_a2_7b",
     "minitron-4b": "repro.configs.minitron_4b",
+    "granite-4.0-h-small": "repro.configs.granite_4_0_h_small",
 }
 
 ARCH_IDS = tuple(_ARCH_MODULES)

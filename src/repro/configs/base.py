@@ -14,7 +14,8 @@ from typing import Optional, Tuple
 # Layer kinds used in ModelConfig.layer_pattern:
 #   F  full causal self-attention + MLP
 #   W  sliding-window causal self-attention + MLP
-#   M  Mamba2 (SSD) block (attention-free)
+#   M  Mamba2 (SSD) block (attention-free); where the layer uses MoE, the
+#      MoE tail of F/W layers follows it
 #   Y  hybrid block: parallel attention + mamba heads (Hymba-style)
 # The pattern string is tiled to ``num_layers`` (e.g. gemma3 "WWWWWF").
 # ---------------------------------------------------------------------------
@@ -49,6 +50,15 @@ class MoEConfig:
     # overhead linearly (at the cost of more scan iterations)
     gshard_group_size: int = 2048
     capacity_factor: float = 1.25
+    # the experts this device holds under expert parallelism: experts
+    # [first_held_expert, first_held_expert + held_experts) of the
+    # num_experts the router scores; 0 holds all of them
+    held_experts: int = 0
+    first_held_expert: int = 0
+
+    def held(self) -> int:
+        """How many experts this device holds."""
+        return self.held_experts or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,10 +104,18 @@ class ModelConfig:
     sliding_window: int = 0  # required if pattern contains W
     mlp_kind: str = "silu_gated"  # silu_gated | gelu_gated | gelu
     norm_kind: str = "rmsnorm"  # rmsnorm | layernorm
-    rope_theta: float = 10000.0
+    rope_theta: Optional[float] = 10000.0  # None: no position embedding (NoPE)
     tie_embeddings: bool = True
     logit_softcap: float = 0.0
     scale_embeddings: bool = False  # gemma-family: embeds *= sqrt(d_model)
+    # published model constants (granite-4.0-h): attention scores times
+    # attention_multiplier (None: 1/sqrt(head_dim)), embeddings times
+    # embedding_multiplier, each residual branch times
+    # residual_multiplier, logits over logits_scaling; 1.0 adds no op
+    attention_multiplier: Optional[float] = None
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     moe_impl: str = "ragged"  # ragged | gshard (dispatch implementation)
     # >0: streaming cross-entropy over vocab chunks of this size (never
     # materialises the (tokens, V) fp32 logits — §Perf HC3)
@@ -117,6 +135,18 @@ class ModelConfig:
 
     # distribution defaults (overridable per round-plan)
     remat: bool = True
+
+    def __post_init__(self):
+        mo = self.moe
+        if mo is not None and mo.held() != mo.num_experts:
+            if self.moe_impl == "gshard":
+                raise ValueError("an expert share (held_experts) needs the "
+                                 "dropless ragged dispatch, not moe_impl='gshard'")
+            if not (0 <= mo.first_held_expert
+                    and mo.first_held_expert + mo.held() <= mo.num_experts):
+                raise ValueError(f"held experts [{mo.first_held_expert}, "
+                                 f"{mo.first_held_expert + mo.held()}) lie outside "
+                                 f"the {mo.num_experts} the router scores")
 
     def pattern_for_layers(self) -> str:
         p = (self.layer_pattern * ((self.num_layers // len(self.layer_pattern)) + 1))

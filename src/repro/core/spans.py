@@ -21,14 +21,17 @@ GRAD_PROJECT = "update_space.grad_project"  # project_dev_ms
 MODEL_BLOCKS = "model.blocks"       # inside local_step_dev_ms
 MODEL_HEAD = "model.head"           # inside local_step_dev_ms
 ATTENTION = "model.attention"       # inside model.blocks: attention_block's core
+MOE = "model.moe"                   # inside model.blocks: router, dispatch, experts,
+                                    # combine, shared expert
+MOE_EXPERTS = "model.moe.experts"   # inside model.moe: the routed experts' matmuls
 SOLVER_STEP = "solver.step"         # inside local_step_dev_ms
 CONTROL = "scaffold.control"        # agg_dev_ms: the client's c_i update
 AGGREGATE = "scaffold.aggregate"    # agg_dev_ms: weighted dy/dc means
 SERVER = "scaffold.server"          # agg_dev_ms: server step, c, metrics
 
 DEVICE_SCOPES = (SAMPLE, BATCHES, GATHER, SCATTER, CLIENT, LOCAL_STEP,
-                 APPLY, GRAD_PROJECT, MODEL_BLOCKS, MODEL_HEAD, ATTENTION,
-                 SOLVER_STEP, CONTROL, AGGREGATE, SERVER)
+                 APPLY, GRAD_PROJECT, MODEL_BLOCKS, MODEL_HEAD, ATTENTION, MOE,
+                 MOE_EXPERTS, SOLVER_STEP, CONTROL, AGGREGATE, SERVER)
 
 # host spans, around one scanned chunk
 CHUNK = "scaffold.chunk"        # args rounds, round; self time: bookkeeping

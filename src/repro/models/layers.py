@@ -305,25 +305,28 @@ def attention_block(cfg, p, x, positions, *, kind: str, prefix_len: int = 0,
     q = (x @ p["wq"]).reshape(b, s, h, d)
     k = (x @ p["wk"]).reshape(b, s, hkv, d)
     v = (x @ p["wv"]).reshape(b, s, hkv, d)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.rope_theta is not None:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+    scale = cfg.attention_multiplier
     with jax.named_scope(spans.ATTENTION):
         if kind == "W":
             w = cfg.sliding_window
-            if swa.takes(s, w):
+            if swa.takes(s, w) and scale is None:
                 out = swa.swa_attention(q, k, v, w)
             elif s % w == 0 and s >= 2 * w:
-                out = local_attention_jnp(q, k, v, window=w)
+                out = local_attention_jnp(q, k, v, window=w, scale=scale)
             else:
-                out = dense_attention(q, k, v, mask_kind="sliding", window=w)
+                out = dense_attention(q, k, v, mask_kind="sliding", window=w,
+                                      scale=scale)
         else:
             mask_kind = "prefix" if prefix_len else "causal"
             if s > use_flash_threshold:
                 out = flash_attention_jnp(q, k, v, mask_kind=mask_kind,
-                                          prefix_len=prefix_len)
+                                          prefix_len=prefix_len, scale=scale)
             else:
                 out = dense_attention(q, k, v, mask_kind=mask_kind,
-                                      prefix_len=prefix_len)
+                                      prefix_len=prefix_len, scale=scale)
     return out.reshape(b, s, h * d) @ p["wo"]
 
 
@@ -334,8 +337,9 @@ def attention_decode(cfg, p, x, cache, pos, *, kind: str):
     q = (x @ p["wq"]).reshape(b, 1, h, d)
     k = (x @ p["wk"]).reshape(b, 1, hkv, d)
     v = (x @ p["wv"]).reshape(b, 1, hkv, d)
-    q = apply_rope(q, pos[:, None], cfg.rope_theta)
-    k = apply_rope(k, pos[:, None], cfg.rope_theta)
+    if cfg.rope_theta is not None:
+        q = apply_rope(q, pos[:, None], cfg.rope_theta)
+        k = apply_rope(k, pos[:, None], cfg.rope_theta)
     c = cache["k"].shape[1]
     window = cfg.sliding_window if kind == "W" else 0
     slot = (pos % c) if (window and c == window) else pos
@@ -345,7 +349,8 @@ def attention_decode(cfg, p, x, cache, pos, *, kind: str):
     v_cache = jax.vmap(lambda buf, vv, i: lax.dynamic_update_slice(buf, vv, (i, 0, 0)))(
         cache["v"], v.astype(cache["v"].dtype), slot
     )
-    out = decode_attention(q, k_cache, v_cache, pos, window=window)
+    out = decode_attention(q, k_cache, v_cache, pos, window=window,
+                           scale=cfg.attention_multiplier)
     out = out.reshape(b, 1, h * d) @ p["wo"]
     return out, {"k": k_cache, "v": v_cache}
 
@@ -499,14 +504,16 @@ def mlp_block(cfg, p, x):
 
 
 def init_moe(cfg, key, dtype):
+    """The router scores all ``num_experts``; the expert weights are those
+    of the experts this device holds (``MoEConfig.held``)."""
     mo = cfg.moe
-    e, f = cfg.d_model, mo.expert_d_ff
+    e, f, n = cfg.d_model, mo.expert_d_ff, mo.held()
     ks = jax.random.split(key, 5)
     p = {
         "router": dense_init(ks[0], (e, mo.num_experts), dtype, scale=0.02),
-        "w_gate": dense_init(ks[1], (mo.num_experts, e, f), dtype),
-        "w_up": dense_init(ks[2], (mo.num_experts, e, f), dtype),
-        "w_down": dense_init(ks[3], (mo.num_experts, f, e), dtype),
+        "w_gate": dense_init(ks[1], (n, e, f), dtype),
+        "w_up": dense_init(ks[2], (n, e, f), dtype),
+        "w_down": dense_init(ks[3], (n, f, e), dtype),
     }
     if mo.num_shared_experts:
         fs = mo.shared_d_ff * mo.num_shared_experts
@@ -536,23 +543,41 @@ def _router(cfg, p, xf):
 
 
 def moe_block_ragged(cfg, p, x):
-    """Sort-by-expert + lax.ragged_dot grouped matmul (TPU-native path)."""
+    """Dropless routed experts by sort + ``lax.ragged_dot`` grouped matmuls.
+
+    The router scores all ``num_experts``; this device computes the part
+    of the result that its held experts give (all of them unless
+    ``MoEConfig.held_experts`` sets a share). Every (token, choice) pair
+    routed to a held expert is computed, whatever the skew: at most
+    min(top_k, held) of a token's choices can land here, so the sorted
+    buffer has T * min(top_k, held) rows, held choices first by expert.
+    Rows past the routed ones belong to no group. On the TPU the grouped
+    matmuls leave those rows of their results unwritten, so they are
+    masked on both sides: zero inputs, and zero outputs (and so zero
+    gradients into the scatter of the backward pass).
+    """
     mo = cfg.moe
+    n_held = mo.held()
     b, s, e = x.shape
     xf = x.reshape(b * s, e)
     t = xf.shape[0]
     w, ids, aux = _router(cfg, p, xf)
-    flat_ids = ids.reshape(-1)  # (T*k,)
-    sort_idx = jnp.argsort(flat_ids)
-    tok_idx = sort_idx // mo.top_k
-    xs = xf[tok_idx]  # (T*k, E)
-    group_sizes = jnp.bincount(flat_ids, length=mo.num_experts).astype(jnp.int32)
+    local = ids.reshape(-1) - mo.first_held_expert  # (T*k,)
+    held = (local >= 0) & (local < n_held)
+    slot = jnp.where(held, local, n_held)  # choices of absent experts sort last
+    rows = t * min(mo.top_k, n_held)
+    order = jnp.argsort(slot)[:rows]
+    tok_idx = order // mo.top_k
+    group_sizes = jnp.bincount(slot, length=n_held + 1)[:n_held].astype(jnp.int32)
+    routed = (jnp.arange(rows) < group_sizes.sum())[:, None]
     act = jax.nn.silu if cfg.mlp_kind != "gelu_gated" else jax.nn.gelu
-    g = lax.ragged_dot(xs, p["w_gate"], group_sizes)
-    u = lax.ragged_dot(xs, p["w_up"], group_sizes)
-    h = act(g) * u
-    out_s = lax.ragged_dot(h, p["w_down"], group_sizes)  # (T*k, E)
-    wsort = w.reshape(-1)[sort_idx][:, None].astype(out_s.dtype)
+    xs = jnp.where(routed, xf[tok_idx], 0)  # (rows, E)
+    with jax.named_scope(spans.MOE_EXPERTS):
+        g = lax.ragged_dot(xs, p["w_gate"], group_sizes)
+        u = lax.ragged_dot(xs, p["w_up"], group_sizes)
+        out_s = lax.ragged_dot(act(g) * u, p["w_down"], group_sizes)  # (rows, E)
+    out_s = jnp.where(routed, out_s, 0)
+    wsort = w.reshape(-1)[order][:, None].astype(out_s.dtype)
     out = jnp.zeros((t, e), out_s.dtype).at[tok_idx].add(out_s * wsort)
     out = out.reshape(b, s, e).astype(x.dtype)
     return out + _shared_expert(cfg, p, x), aux
@@ -597,9 +622,10 @@ def moe_block_gshard(cfg, p, x, *, capacity_factor: Optional[float] = None,
         # receives <= 1 token, so only the combine weights round
         disp_c = disp.astype(x.dtype)
         xin = jnp.einsum("gec,gd->ecd", disp_c, xg)
-        hg = act(jnp.einsum("ecd,edf->ecf", xin, p["w_gate"]))
-        hu = jnp.einsum("ecd,edf->ecf", xin, p["w_up"])
-        ho = jnp.einsum("ecf,efd->ecd", hg * hu, p["w_down"])
+        with jax.named_scope(spans.MOE_EXPERTS):
+            hg = act(jnp.einsum("ecd,edf->ecf", xin, p["w_gate"]))
+            hu = jnp.einsum("ecd,edf->ecf", xin, p["w_up"])
+            ho = jnp.einsum("ecf,efd->ecd", hg * hu, p["w_down"])
         return jnp.einsum("gec,ecd->gd", comb.astype(x.dtype), ho)
 
     xg = xf.reshape(ng, g, e)
@@ -619,9 +645,10 @@ def _shared_expert(cfg, p, x):
 
 
 def moe_block(cfg, p, x, impl: str = "ragged"):
-    if impl == "gshard":
-        return moe_block_gshard(cfg, p, x)
-    return moe_block_ragged(cfg, p, x)
+    with jax.named_scope(spans.MOE):
+        if impl == "gshard":
+            return moe_block_gshard(cfg, p, x)
+        return moe_block_ragged(cfg, p, x)
 
 
 # ---------------------------------------------------------------------------

@@ -58,6 +58,8 @@ def _embed(cfg, params, tokens):
     x = params["embed"][tokens]
     if cfg.scale_embeddings:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     return constrain_batch_dim(x.astype(_dtype(cfg.compute_dtype)))
 
 
@@ -66,6 +68,8 @@ def _unembed(cfg, params, x):
         logits = x @ params["embed"].T.astype(x.dtype)
     else:
         logits = x @ params["unembed"]
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     if cfg.logit_softcap:
         logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
     return logits
@@ -136,6 +140,8 @@ def _chunked_ce(cfg, params, hidden, labels, mask):
         m, acc, gold = carry
         w_chunk, idx = inp
         logits = (hidden @ w_chunk.T).astype(jnp.float32)  # (B,S,C)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         if cfg.logit_softcap:
             logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
         lo = idx * chunk
@@ -303,13 +309,14 @@ def count_params_analytic(cfg) -> int:
 
 
 def count_active_params(cfg) -> int:
-    """Active params per token (MoE: routed experts count top_k/E)."""
+    """Active params per token (MoE: of the routed experts held here, the
+    top_k * held / num_experts a token uses on average)."""
     total = count_params_analytic(cfg)
     if cfg.moe is None:
         return total
     mo = cfg.moe
     n_moe_layers = sum(cfg.layer_uses_moe(i) for i in range(cfg.num_layers))
     per_expert = 3 * cfg.d_model * mo.expert_d_ff
-    routed = n_moe_layers * mo.num_experts * per_expert
-    active_routed = n_moe_layers * mo.top_k * per_expert
+    routed = n_moe_layers * mo.held() * per_expert
+    active_routed = n_moe_layers * mo.top_k * mo.held() * per_expert // mo.num_experts
     return total - routed + active_routed

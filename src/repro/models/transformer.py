@@ -47,6 +47,12 @@ def layer_groups(cfg) -> List[LayerGroup]:
 # ---------------------------------------------------------------------------
 
 
+def _has_ffn_tail(kind: str, uses_moe: bool) -> bool:
+    """F, W and Y layers end in an MLP or MoE; an M layer only where it
+    uses MoE (granite-4.0-h), else its mixer is the whole layer."""
+    return kind != "M" or uses_moe
+
+
 def _init_layer(cfg, key, kind: str, uses_moe: bool, has_cross: bool, dtype):
     ks = jax.random.split(key, 6)
     p: Dict[str, Any] = {}
@@ -55,6 +61,7 @@ def _init_layer(cfg, key, kind: str, uses_moe: bool, has_cross: bool, dtype):
         p["attn"] = (
             L.init_mla(cfg, ks[1], dtype) if cfg.mla else L.init_attention(cfg, ks[1], dtype)
         )
+    if _has_ffn_tail(kind, uses_moe):
         p["ln_mlp"] = L.init_norm(cfg, ks[2], cfg.d_model, dtype)
         if uses_moe:
             p["moe"] = L.init_moe(cfg, ks[3], dtype)
@@ -87,87 +94,87 @@ def _cross_attention(cfg, p, x, enc_out):
     return out.reshape(b, s, h * d) @ p["wo"]
 
 
+def _residual(cfg, x, branch):
+    """x + branch, the branch times ``residual_multiplier`` where set."""
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * jnp.asarray(cfg.residual_multiplier, branch.dtype)
+    return x + branch
+
+
+def _ffn_tail(cfg, p, x, uses_moe: bool, moe_impl: str):
+    """The layer's MLP or MoE on its normed residual stream; returns
+    (x, aux_loss)."""
+    h2 = L.apply_norm(cfg, x, p["ln_mlp"])
+    if uses_moe:
+        out, aux = L.moe_block(cfg, p["moe"], h2, impl=moe_impl)
+    else:
+        out, aux = L.mlp_block(cfg, p["mlp"], h2), jnp.zeros((), jnp.float32)
+    return _residual(cfg, x, out), aux
+
+
 def _apply_layer(cfg, p, x, positions, kind: str, uses_moe: bool, *,
                  prefix_len: int = 0, enc_out=None, moe_impl: str = "ragged"):
     """Full-sequence layer forward. Returns (x, aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
+    h_in = L.apply_norm(cfg, x, p["ln_attn"])
     if kind == "Y":
         # Hymba-style: attention and mamba heads in parallel on the same input
-        h_in = L.apply_norm(cfg, x, p["ln_attn"])
         attn_out = L.attention_block(cfg, p["attn"], h_in, positions, kind="W"
                                      if cfg.sliding_window else "F",
                                      prefix_len=prefix_len)
         mamba_out = L.mamba_block(cfg, p["mamba"], h_in)
-        x = x + 0.5 * (attn_out + mamba_out)
-        h2 = L.apply_norm(cfg, x, p["ln_mlp"])
-        x = x + L.mlp_block(cfg, p["mlp"], h2)
-        return x, aux
-    if kind == "M":
-        h_in = L.apply_norm(cfg, x, p["ln_attn"])
-        x = x + L.mamba_block(cfg, p["mamba"], h_in)
-        return x, aux
-    # F / W
-    h_in = L.apply_norm(cfg, x, p["ln_attn"])
-    if cfg.mla:
-        attn_out = L.mla_block(cfg, p["attn"], h_in, positions, prefix_len=prefix_len)
-    else:
-        attn_out = L.attention_block(cfg, p["attn"], h_in, positions, kind=kind,
-                                     prefix_len=prefix_len)
-    x = x + attn_out
-    if enc_out is not None:
-        hc = L.apply_norm(cfg, x, p["ln_cross"])
-        x = x + _cross_attention(cfg, p["cross"], hc, enc_out)
-    h2 = L.apply_norm(cfg, x, p["ln_mlp"])
-    if uses_moe:
-        moe_out, aux = L.moe_block(cfg, p["moe"], h2, impl=moe_impl)
-        x = x + moe_out
-    else:
-        x = x + L.mlp_block(cfg, p["mlp"], h2)
-    return x, aux
+        x = _residual(cfg, x, 0.5 * (attn_out + mamba_out))
+    elif kind == "M":
+        x = _residual(cfg, x, L.mamba_block(cfg, p["mamba"], h_in))
+    else:  # F / W
+        if cfg.mla:
+            attn_out = L.mla_block(cfg, p["attn"], h_in, positions,
+                                   prefix_len=prefix_len)
+        else:
+            attn_out = L.attention_block(cfg, p["attn"], h_in, positions,
+                                         kind=kind, prefix_len=prefix_len)
+        x = _residual(cfg, x, attn_out)
+        if enc_out is not None:
+            hc = L.apply_norm(cfg, x, p["ln_cross"])
+            x = x + _cross_attention(cfg, p["cross"], hc, enc_out)
+    if not _has_ffn_tail(kind, uses_moe):
+        return x, jnp.zeros((), jnp.float32)
+    return _ffn_tail(cfg, p, x, uses_moe, moe_impl)
 
 
 def _decode_layer(cfg, p, x, cache, pos, kind: str, uses_moe: bool, *,
                   moe_impl: str = "ragged"):
     """One-token layer decode. Returns (x, new_cache)."""
     new_cache = dict(cache)
+    h_in = L.apply_norm(cfg, x, p["ln_attn"])
     if kind == "Y":
-        h_in = L.apply_norm(cfg, x, p["ln_attn"])
         attn_out, new_cache["attn"] = L.attention_decode(
             cfg, p["attn"], h_in, cache["attn"], pos,
             kind="W" if cfg.sliding_window else "F")
         mamba_out, new_cache["mamba"] = L.mamba_decode(cfg, p["mamba"], h_in,
                                                        cache["mamba"], pos)
-        x = x + 0.5 * (attn_out + mamba_out)
-        h2 = L.apply_norm(cfg, x, p["ln_mlp"])
-        x = x + L.mlp_block(cfg, p["mlp"], h2)
-        return x, new_cache
-    if kind == "M":
-        h_in = L.apply_norm(cfg, x, p["ln_attn"])
+        x = _residual(cfg, x, 0.5 * (attn_out + mamba_out))
+    elif kind == "M":
         out, new_cache["mamba"] = L.mamba_decode(cfg, p["mamba"], h_in,
                                                  cache["mamba"], pos)
-        return x + out, new_cache
-    h_in = L.apply_norm(cfg, x, p["ln_attn"])
-    if cfg.mla:
-        attn_out, new_cache["attn"] = L.mla_decode(cfg, p["attn"], h_in,
-                                                   cache["attn"], pos)
+        x = _residual(cfg, x, out)
     else:
-        attn_out, new_cache["attn"] = L.attention_decode(cfg, p["attn"], h_in,
-                                                         cache["attn"], pos, kind=kind)
-    x = x + attn_out
-    if "cross_kv" in cache:
-        hc = L.apply_norm(cfg, x, p["ln_cross"])
-        b = x.shape[0]
-        h, d = cfg.num_heads, cfg.head_dim
-        q = (hc @ p["cross"]["wq"]).reshape(b, 1, h, d)
-        ck, cv = cache["cross_kv"]
-        out = L.dense_attention(q, ck, cv, mask_kind="full")
-        x = x + out.reshape(b, 1, h * d) @ p["cross"]["wo"]
-    h2 = L.apply_norm(cfg, x, p["ln_mlp"])
-    if uses_moe:
-        moe_out, _ = L.moe_block(cfg, p["moe"], h2, impl=moe_impl)
-        x = x + moe_out
-    else:
-        x = x + L.mlp_block(cfg, p["mlp"], h2)
+        if cfg.mla:
+            attn_out, new_cache["attn"] = L.mla_decode(cfg, p["attn"], h_in,
+                                                       cache["attn"], pos)
+        else:
+            attn_out, new_cache["attn"] = L.attention_decode(
+                cfg, p["attn"], h_in, cache["attn"], pos, kind=kind)
+        x = _residual(cfg, x, attn_out)
+        if "cross_kv" in cache:
+            hc = L.apply_norm(cfg, x, p["ln_cross"])
+            b = x.shape[0]
+            h, d = cfg.num_heads, cfg.head_dim
+            q = (hc @ p["cross"]["wq"]).reshape(b, 1, h, d)
+            ck, cv = cache["cross_kv"]
+            out = L.dense_attention(q, ck, cv, mask_kind="full")
+            x = x + out.reshape(b, 1, h * d) @ p["cross"]["wo"]
+    if _has_ffn_tail(kind, uses_moe):
+        x, _ = _ffn_tail(cfg, p, x, uses_moe, moe_impl)
     return x, new_cache
 
 

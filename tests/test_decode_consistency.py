@@ -17,6 +17,7 @@ DECODE_ARCHS = [
     ("qwen2-moe-a2.7b", 32),
     ("deepseek-v3-671b", 32),
     ("minitron-4b", 32),
+    ("granite-4.0-h-small", 32),  # M and F layers with MoE tails, NoPE
 ]
 
 

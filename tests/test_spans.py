@@ -18,8 +18,8 @@ from repro.data.synthetic_lm import SyntheticLMFederated
 from repro.models import model as M
 
 
-def _trainer(strategy="client_sequential"):
-    cfg = get_reduced("llama3.2-3b")
+def _trainer(strategy="client_sequential", arch="llama3.2-3b"):
+    cfg = get_reduced(arch)
     spec = FedRoundSpec(algorithm="scaffold", num_clients=4, num_sampled=2,
                         local_steps=2, local_batch=1, eta_l=0.1,
                         strategy=strategy, update_space="lora", lora_rank=4)
@@ -38,7 +38,8 @@ def _scopes_of(op_name):
 
 @pytest.mark.parametrize("strategy", ["client_sequential", "client_parallel"])
 def test_every_device_scope_names_ops_of_the_chunk(strategy):
-    tr = _trainer(strategy)
+    # a stack of Mamba-2 and attention layers with MoE tails carries them all
+    tr = _trainer(strategy, "granite-4.0-h-small")
     text = tr._scan_fn.lower(
         tr.server, tr.device_store, tr._device_data, tr.device_sampler.key,
         tr._data_base_key, None, None, tr._device_sizes, 0, 1,

@@ -176,3 +176,26 @@ def test_window_layer_selects_kernel_by_shape(window, seq, kernel, one_chip,
     text = jax.jit(lambda p, x, pos: L.attention_block(
         cfg, p, x, pos, kind="W")).lower(p, x, pos).as_text()
     assert ("tpu_custom_call" in text) == kernel
+
+
+def test_moe_share_compiles_to_grouped_matmuls(one_chip, no_persistent_cache):
+    """granite-4.0-h-small's MoE tail at its widths (d 4096, 9 of 72
+    experts of 768 held, top-10, 2048 tokens), forward and the gradient
+    into its input: the TPU compiler takes the share's grouped matmuls as
+    its own ragged-dot kernels."""
+    from repro.configs import get_config
+    from repro.models import layers as L
+
+    cfg = get_config("granite-4.0-h-small")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, held_experts=9))
+    put = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    p = put(jax.eval_shape(lambda: L.init_moe(cfg, jax.random.key(0), jnp.bfloat16)))
+    x = put(jax.ShapeDtypeStruct((1, 2048, cfg.d_model), jnp.bfloat16))
+
+    def loss(p, x):
+        out, _ = L.moe_block(cfg, p, x)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=1)).lower(p, x).compile().as_text()
+    assert "tpu_custom_call" in text and "ragged-dot" in text
