@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -542,6 +542,78 @@ def _router(cfg, p, xf):
     return w, ids, aux
 
 
+class _Route(NamedTuple):
+    """Where the (token, choice) pairs of ``moe_block_ragged`` go: the
+    sorted buffer's rows come from pairs ``order`` of tokens ``tok_idx``,
+    the first ``routed`` of them held; pair (t, j), where ``held``, sits
+    at row ``pos[t, j]``."""
+    order: jax.Array    # (rows,)
+    tok_idx: jax.Array  # (rows,)
+    routed: jax.Array   # (rows, 1)
+    pos: jax.Array      # (T, top_k), 0 where not held
+    held: jax.Array     # (T, top_k)
+
+
+def _sum_choices(y, route, w=None):
+    """``out[t] = sum_j held[t, j] * w[t, j] * y[pos[t, j]]`` in float32:
+    each token's rows gathered back from the buffer and summed over its
+    choices. The rows are selected with ``where``, so rows never picked
+    (past the groups, unwritten on the TPU) leak no NaN into the sum. One
+    (T, E) gather per choice: the TPU's gathers do not fuse into the sum,
+    and one (T, top_k, E) gather would be written out whole."""
+    out = 0
+    for j in range(route.pos.shape[1]):
+        row = jnp.where(route.held[:, j, None], y[route.pos[:, j]], 0)
+        row = row.astype(jnp.float32)
+        out = out + (row if w is None else row * w[:, j, None])
+    return out
+
+
+@jax.custom_vjp
+def _dispatch(xf, route):
+    """Token rows into the sorted buffer: ``xf[tok_idx]`` on the routed
+    rows, zero past them. Its transpose gathers by ``pos`` and sums over
+    the choices, instead of scatter-adding the rows back."""
+    return jnp.where(route.routed, xf[route.tok_idx], 0)
+
+
+def _dispatch_fwd(xf, route):
+    return _dispatch(xf, route), route
+
+
+def _dispatch_bwd(route, d_xs):
+    return _sum_choices(d_xs, route).astype(d_xs.dtype), None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(out_s, w, route):
+    """The buffer's rows back into token order, weighted by the router,
+    summed in float32 and cast once. The cotangent of ``out_s`` gathers
+    ``d_out`` by ``tok_idx``; that of ``w`` is the dot of each routed
+    row with its token's ``d_out``, gathered back by ``pos``."""
+    return _sum_choices(out_s, route, w).astype(out_s.dtype)
+
+
+def _combine_fwd(out_s, w, route):
+    return _combine(out_s, w, route), (out_s, w, route)
+
+
+def _combine_bwd(res, d_out):
+    out_s, w, route = res
+    d_rows = d_out[route.tok_idx].astype(jnp.float32)  # (rows, E)
+    wsort = w.reshape(-1)[route.order][:, None]
+    d_out_s = jnp.where(route.routed, d_rows * wsort, 0)
+    d_wsort = jnp.where(route.routed, d_rows * out_s.astype(jnp.float32), 0).sum(-1)
+    d_w = jnp.where(route.held, d_wsort[route.pos], 0)
+    return d_out_s.astype(out_s.dtype), d_w.astype(w.dtype), None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def moe_block_ragged(cfg, p, x):
     """Dropless routed experts by sort + ``lax.ragged_dot`` grouped matmuls.
 
@@ -551,10 +623,21 @@ def moe_block_ragged(cfg, p, x):
     routed to a held expert is computed, whatever the skew: at most
     min(top_k, held) of a token's choices can land here, so the sorted
     buffer has T * min(top_k, held) rows, held choices first by expert.
+
+    Rows move between token order and the sorted buffer by a permutation
+    and its inverse, never by a scatter-add: ``order`` sorts the pairs,
+    ``tok_idx`` gives each buffer row its token (the dispatch gathers
+    ``x`` by it), and ``pos``, the inverse permutation, gives each
+    (token, choice) pair its row (the combine gathers the experts' rows
+    by it and sums the top_k choices in float32). Each direction's
+    transpose is the other gather (``_dispatch``, ``_combine``), so the
+    backward pass scatters no rows either.
+
     Rows past the routed ones belong to no group. On the TPU the grouped
-    matmuls leave those rows of their results unwritten, so they are
-    masked on both sides: zero inputs, and zero outputs (and so zero
-    gradients into the scatter of the backward pass).
+    matmuls leave those rows of their results unwritten, so the dispatch
+    zeroes them on the input, and the combine selects the held choices'
+    rows with ``where`` (a zero weight would keep NaN * 0) and gives them
+    zero cotangents in the backward pass.
     """
     mo = cfg.moe
     n_held = mo.held()
@@ -562,24 +645,23 @@ def moe_block_ragged(cfg, p, x):
     xf = x.reshape(b * s, e)
     t = xf.shape[0]
     w, ids, aux = _router(cfg, p, xf)
-    local = ids.reshape(-1) - mo.first_held_expert  # (T*k,)
+    local = ids - mo.first_held_expert  # (T, k)
     held = (local >= 0) & (local < n_held)
-    slot = jnp.where(held, local, n_held)  # choices of absent experts sort last
+    slot = jnp.where(held, local, n_held).reshape(-1)  # absent experts sort last
     rows = t * min(mo.top_k, n_held)
-    order = jnp.argsort(slot)[:rows]
-    tok_idx = order // mo.top_k
+    order = jnp.argsort(slot)
+    pos = jnp.argsort(order).reshape(t, mo.top_k)  # the inverse permutation
     group_sizes = jnp.bincount(slot, length=n_held + 1)[:n_held].astype(jnp.int32)
     routed = (jnp.arange(rows) < group_sizes.sum())[:, None]
+    order = order[:rows]
+    route = _Route(order, order // mo.top_k, routed, jnp.where(held, pos, 0), held)
     act = jax.nn.silu if cfg.mlp_kind != "gelu_gated" else jax.nn.gelu
-    xs = jnp.where(routed, xf[tok_idx], 0)  # (rows, E)
+    xs = _dispatch(xf, route)  # (rows, E)
     with jax.named_scope(spans.MOE_EXPERTS):
         g = lax.ragged_dot(xs, p["w_gate"], group_sizes)
         u = lax.ragged_dot(xs, p["w_up"], group_sizes)
         out_s = lax.ragged_dot(act(g) * u, p["w_down"], group_sizes)  # (rows, E)
-    out_s = jnp.where(routed, out_s, 0)
-    wsort = w.reshape(-1)[order][:, None].astype(out_s.dtype)
-    out = jnp.zeros((t, e), out_s.dtype).at[tok_idx].add(out_s * wsort)
-    out = out.reshape(b, s, e).astype(x.dtype)
+    out = _combine(out_s, w, route).reshape(b, s, e).astype(x.dtype)
     return out + _shared_expert(cfg, p, x), aux
 
 

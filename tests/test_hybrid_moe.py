@@ -3,6 +3,7 @@ an MoE tail, an expert share that routes over all experts and computes
 its own experts' part without dropping, NoPE attention at a given scale,
 and the model's multipliers."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -69,20 +70,27 @@ def _dense_share(cfg, p, x):
     return out.reshape(x.shape) + np.asarray(L._shared_expert(cfg, p, x))
 
 
+def _skewed(top_k, held, first):
+    """A share whose router sends every token's choices into the held
+    experts, as many as fit: every token carries a feature that the router
+    reads as a large score for the held experts and a low one for the
+    rest."""
+    cfg = _cfg(top_k=top_k, held=held, first=first)
+    key = jax.random.key(3)
+    p = L.init_moe(cfg, key, jnp.float32)
+    x = jax.random.normal(jax.random.fold_in(key, 1), (2, 16, cfg.d_model))
+    x = x.at[..., 0].set(4.0)
+    p["router"] = p["router"].at[0].set(-2.0).at[0, first:first + held].set(2.0)
+    return cfg, p, x
+
+
 @pytest.mark.parametrize("top_k,held", [(3, 4), (4, 3)])
 def test_share_is_dropless_under_forced_skew(top_k, held):
     """A router that sends every token's choices into the held experts
     (as many as fit) fills the whole buffer, and the share still equals
     the dense sum over the held experts."""
     first = 2
-    cfg = _cfg(top_k=top_k, held=held, first=first)
-    key = jax.random.key(3)
-    p = L.init_moe(cfg, key, jnp.float32)
-    # every token carries a feature that the router reads as a large
-    # score for the held experts and a low one for the rest
-    x = jax.random.normal(jax.random.fold_in(key, 1), (2, 16, cfg.d_model))
-    x = x.at[..., 0].set(4.0)
-    p["router"] = p["router"].at[0].set(-2.0).at[0, first:first + held].set(2.0)
+    cfg, p, x = _skewed(top_k, held, first)
     _, ids, _ = L._router(cfg, p, x.reshape(-1, cfg.d_model))
     local = np.asarray(ids) - first
     assert (((local >= 0) & (local < held)).sum(-1) == min(top_k, held)).all()
@@ -128,6 +136,140 @@ def test_rows_past_the_groups_change_nothing(monkeypatch):
     for a, b in zip(got, want):
         assert bool(jnp.isfinite(a).all())
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+def _scatter_add_share(cfg, p, x):
+    """The share as it was computed before the inverse permutation: the
+    sorted rows gathered by token, and the combine a scatter-add of the
+    weighted expert rows into (T, d_model) (whose transpose, like the
+    dispatch gather's, is a scatter-add)."""
+    mo = cfg.moe
+    n_held = mo.held()
+    b, s, e = x.shape
+    xf = x.reshape(b * s, e)
+    t = xf.shape[0]
+    w, ids, aux = L._router(cfg, p, xf)
+    local = ids.reshape(-1) - mo.first_held_expert
+    held = (local >= 0) & (local < n_held)
+    slot = jnp.where(held, local, n_held)
+    rows = t * min(mo.top_k, n_held)
+    order = jnp.argsort(slot)[:rows]
+    tok_idx = order // mo.top_k
+    group_sizes = jnp.bincount(slot, length=n_held + 1)[:n_held].astype(jnp.int32)
+    routed = (jnp.arange(rows) < group_sizes.sum())[:, None]
+    xs = jnp.where(routed, xf[tok_idx], 0)
+    g = jax.lax.ragged_dot(xs, p["w_gate"], group_sizes)
+    u = jax.lax.ragged_dot(xs, p["w_up"], group_sizes)
+    out_s = jax.lax.ragged_dot(jax.nn.silu(g) * u, p["w_down"], group_sizes)
+    out_s = jnp.where(routed, out_s, 0)
+    wsort = w.reshape(-1)[order][:, None].astype(out_s.dtype)
+    out = jnp.zeros((t, e), out_s.dtype).at[tok_idx].add(out_s * wsort)
+    return out.reshape(b, s, e).astype(x.dtype) + L._shared_expert(cfg, p, x), aux
+
+
+def _value_and_grads(block, cfg, p, x):
+    """The block's output, and the gradient of a random projection of it
+    with respect to x (through the router too) and every weight."""
+    r = jax.random.normal(jax.random.key(9), x.shape)
+    out, _ = block(cfg, p, x)
+    grads = jax.grad(lambda x, p: jnp.vdot(block(cfg, p, x)[0], r), argnums=(0, 1))(x, p)
+    return out, grads
+
+
+@pytest.mark.parametrize("top_k,held,first,skew", [
+    (3, 2, 1, False),  # top_k > held, a share past expert 0
+    (2, 4, 0, False),  # top_k <= held, the first experts
+    (3, 4, 4, False),  # top_k <= held, the last experts
+    (3, 0, 0, False),  # every expert held
+    (3, 4, 2, True),   # forced skew fills the buffer
+    (4, 3, 2, True),
+])
+def test_permutation_matches_scatter_add(top_k, held, first, skew):
+    """Dispatch and combine by the inverse permutation give the value and
+    the gradients of the scatter-add formulation, within float32."""
+    if skew:
+        cfg, p, x = _skewed(top_k, held, first)
+    else:
+        cfg = _cfg(top_k=top_k, held=held, first=first)
+        key = jax.random.key(6)
+        p = L.init_moe(cfg, key, jnp.float32)
+        x = jax.random.normal(jax.random.fold_in(key, 1), (2, 16, cfg.d_model))
+    got = _value_and_grads(L.moe_block_ragged, cfg, p, x)
+    want = _value_and_grads(_scatter_add_share, cfg, p, x)
+    assert set(got[1][1]) >= {"router", "shared"}
+    assert float(jnp.abs(got[1][1]["router"]).max()) > 0
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+def _scatter_shapes(block, cfg, p, x, remat):
+    loss = lambda x, p: jnp.sum(block(cfg, p, x)[0] ** 2)  # noqa: E731
+    if remat:
+        loss = jax.checkpoint(loss, policy=jax.checkpoint_policies.nothing_saveable)
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, p).compile().as_text()
+    return {tuple(int(d) for d in dims.split(",") if d)
+            for dims in re.findall(r"= \w+\[([\d,]*)\]\S* scatter\(", hlo)}
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_no_row_scatter_in_forward_or_backward(remat):
+    """The compiled gradient of the share, rematerialised or not, holds no
+    scatter into a (tokens, d_model) array; the scatter-add formulation
+    holds them (so the check can see one)."""
+    cfg = _cfg(top_k=3, held=2, first=1)
+    p = L.init_moe(cfg, jax.random.key(7), jnp.float32)
+    x = jax.random.normal(jax.random.key(8), (2, 16, cfg.d_model))
+    rows = (2 * 16, cfg.d_model)
+    assert rows not in _scatter_shapes(L.moe_block_ragged, cfg, p, x, remat)
+    assert rows in _scatter_shapes(_scatter_add_share, cfg, p, x, remat)
+
+
+def _leave_rows_unwritten(monkeypatch):
+    """``lax.ragged_dot`` as the TPU runs it: rows past the groups of its
+    result, and of its input's cotangent, hold NaN."""
+    real = jax.lax.ragged_dot
+
+    def unwritten(a, rows):
+        return jnp.where((jnp.arange(a.shape[0]) < rows)[:, None], a, jnp.nan)
+
+    @jax.custom_vjp
+    def ragged_dot(lhs, rhs, group_sizes):
+        return unwritten(real(lhs, rhs, group_sizes), group_sizes.sum())
+
+    def fwd(lhs, rhs, group_sizes):
+        return ragged_dot(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+    def bwd(res, g):
+        lhs, rhs, group_sizes = res
+        d_lhs = real(g, jnp.swapaxes(rhs, 1, 2), group_sizes)
+        d_rhs = jax.vjp(lambda r: real(lhs, r, group_sizes), rhs)[1](g)[0]
+        return unwritten(d_lhs, group_sizes.sum()), d_rhs, None
+
+    ragged_dot.defvjp(fwd, bwd)
+    monkeypatch.setattr(jax.lax, "ragged_dot", ragged_dot)
+
+
+def test_share_that_no_token_picks_is_the_shared_expert(monkeypatch):
+    """When no choice lands on the held experts, every row of the buffer
+    is unwritten (NaN here); the combine and its transpose select rows,
+    so the share is the shared expert alone, value and gradient."""
+    first, held = 2, 3
+    cfg = _cfg(top_k=3, held=held, first=first)
+    key = jax.random.key(3)
+    p = L.init_moe(cfg, key, jnp.float32)
+    x = jax.random.normal(jax.random.fold_in(key, 1), (2, 16, cfg.d_model))
+    x = x.at[..., 0].set(4.0)
+    p["router"] = p["router"].at[0].set(2.0).at[0, first:first + held].set(-2.0)
+    _, ids, _ = L._router(cfg, p, x.reshape(-1, cfg.d_model))
+    assert not ((np.asarray(ids) >= first) & (np.asarray(ids) < first + held)).any()
+    _leave_rows_unwritten(monkeypatch)
+    out, grads = _value_and_grads(L.moe_block_ragged, cfg, p, x)
+    want = L._shared_expert(cfg, p, x)
+    r = jax.random.normal(jax.random.key(9), x.shape)
+    want_dx = jax.grad(lambda x: jnp.vdot(L._shared_expert(cfg, p, x), r))(x)
+    np.testing.assert_allclose(out, want, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(grads[0], want_dx, rtol=1e-5, atol=1e-6)
+    assert all(bool(jnp.isfinite(g).all()) for g in jax.tree.leaves(grads))
 
 
 def test_share_with_gshard_is_refused():
